@@ -2,14 +2,14 @@
 """Diff two bench result files — the regression gate for BENCH_*.json.
 
 ``bench.py`` emits its per-row numbers as a ``{"details": {row: {...}}}``
-JSON line on stderr; the repo's archived ``BENCH_r*.json`` artifacts wrap
-that whole invocation as ``{"n", "cmd", "rc", "tail", "parsed"}`` with
-the details line embedded somewhere inside the ``tail`` string. This
-tool accepts EITHER form on either side (plus a bare row-mapping), so
+JSON line on stderr; a driver record wraps that whole invocation as
+``{"n", "cmd", "rc", "tail", "parsed"}`` with the details line embedded
+somewhere inside the ``tail`` string. This tool accepts EITHER form on
+either side (plus a bare row-mapping), so
 
-    python hack/bench_diff.py BENCH_r05.json BENCH_r06.json
+    python hack/bench_diff.py old_record.json new_record.json
 
-compares two archived rounds and
+compares two recorded runs and
 
     python hack/bench_diff.py old.json new.json --strict
 

@@ -141,7 +141,7 @@ import tabnanny
 import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-TARGETS = ["kube_batch_tpu", "tests", "bench.py", "__graft_entry__.py", "hack"]
+TARGETS = ["kube_batch_tpu", "tests", "bench.py", "__graft_entry__.py", "chip_smoke.py", "hack"]
 
 # Names a module may import without using (re-export / side-effect
 # registration idioms used deliberately in this codebase).

@@ -125,6 +125,10 @@ class XlaAllocateAction(Action):
         # "sharded_xla", "pallas", "xla", "serial"); bench rows assert on
         # this so a silent downgrade cannot masquerade as evidence.
         self.last_solver_tier = "none"
+        # Block backend of the last mesh_pallas solve ("mosaic",
+        # "interpret", "jnp"; "" off that rung): chip_smoke asserts the
+        # real kernel ran.
+        self.last_block_impl = ""
         # Gang iterations the last execute() committed from K-deep
         # batched mesh exchanges (KBT_EXCHANGE_BATCH; 0 off the batched
         # program). Bench rows read this as amortization evidence.
@@ -153,6 +157,7 @@ class XlaAllocateAction(Action):
 
         self.last_timings = {}  # never report a previous cycle's path
         self.last_solver_tier = "none"
+        self.last_block_impl = ""
         self.last_batched_iters = 0
         self.last_class_stats = None
         if not _kernel_supported(ssn):
@@ -163,7 +168,7 @@ class XlaAllocateAction(Action):
         mesh = self._resolve_mesh(ssn)
 
         # Size floor: one device solve costs a fixed dispatch round trip
-        # (~0.1 s over a remote chip) regardless of payload, while the
+        # regardless of payload (not measured on current code), while the
         # serial loop clears tiny snapshots in microseconds-per-pair —
         # route (tasks x nodes) below the floor to the serial action
         # (bit-exact float64, no device). A mesh *request* — even one
@@ -755,6 +760,7 @@ class XlaAllocateAction(Action):
                                 )
                             ladder.record_success("mesh_pallas")
                             self.last_solver_tier = "mesh_pallas"
+                            self.last_block_impl = mp.block_impl
                             return out
                         except Exception:
                             log.exception(

@@ -63,11 +63,25 @@ def _uniform_nodes(n_nodes: int, cpu: int = 16, mem_mi: int = 32768, pods: int =
 def synthetic(n_pods: int = 1000, n_nodes: int = 100, tasks_per_job: int = 10, seed: int = 0) -> ClusterInfo:
     """Config 2: kubemark-style hollow density state — small gang jobs,
     one queue."""
+    return build_cluster(*synthetic_objects(n_pods, n_nodes, tasks_per_job, seed))
+
+
+def synthetic_objects(
+    n_pods: int = 1000,
+    n_nodes: int = 100,
+    tasks_per_job: int = 10,
+    seed: int = 0,
+    prefix: str = "job",
+) -> tuple[list, list, list, list]:
+    """The API objects behind `synthetic`: (pods, nodes, pod groups,
+    queues), fresh on every call, for seeding a `ClusterStore` through
+    its create calls. ``prefix`` names the gangs, so a later wave of
+    arrivals does not collide with the first."""
     rng = random.Random(seed)
     pods, pgs = [], []
     n_jobs = max(n_pods // tasks_per_job, 1)
     for j in range(n_jobs):
-        name = f"job-{j:05d}"
+        name = f"{prefix}-{j:05d}"
         pgs.append(build_pod_group(name, min_member=max(tasks_per_job // 2, 1)))
         for t in range(tasks_per_job):
             pods.append(
@@ -80,7 +94,7 @@ def synthetic(n_pods: int = 1000, n_nodes: int = 100, tasks_per_job: int = 10, s
                     ),
                 )
             )
-    return build_cluster(pods, _uniform_nodes(n_nodes), pgs, [build_queue("default")])
+    return pods, _uniform_nodes(n_nodes), pgs, [build_queue("default")]
 
 
 def multi_queue(

@@ -14,45 +14,57 @@ property tests pin serial ≡ XLA assignment-for-assignment.
 import os as _os
 
 
-def enable_compilation_cache() -> None:
+# Default persistent compile cache: a fixed directory inside the
+# checkout (gitignored). JAX keys its entries on the path, so the path
+# must not move between runs.
+_CHECKOUT_CACHE = _os.path.join(
+    _os.path.dirname(_os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
+def enable_compilation_cache() -> str | None:
     """Persistent XLA compilation cache: the solve recompiles only when a
     padding bucket changes shape, but a fresh process (server restart,
-    bench run, failover standby taking over) pays each bucket's 10-30 s
-    trace+compile again without one. Opt-out with KBT_JAX_CACHE=0 or
-    point KBT_JAX_CACHE at a directory.
+    bench run, failover standby taking over) pays each bucket's compile
+    again without one. Returns the directory in use, or None when off.
+
+    - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and this
+      sets nothing.
+    - otherwise ``<checkout>/.jax_cache/``;
+    - ``KBT_JAX_CACHE=0`` turns the cache off.
 
     Called by the scheduler entry points (Scheduler init, bench, the
     graft entry) — deliberately NOT at import, so an embedding
     application that configures jax itself keeps full control no matter
     the import order; it defers to any cache dir already set."""
-    spec = _os.environ.get("KBT_JAX_CACHE", "")
-    if spec == "0":
-        return
-    try:
-        import jax
+    env_dir = _os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    if _os.environ.get("KBT_JAX_CACHE", "") == "0":
+        return None
+    import jax
 
-        # Respect an embedding application's own cache configuration
-        # (env or explicit jax.config) — only fill the gap.
-        if getattr(jax.config, "jax_compilation_cache_dir", None) or _os.environ.get(
-            "JAX_COMPILATION_CACHE_DIR"
-        ):
-            return
-        path = spec or _os.path.join(
-            _os.path.expanduser("~"), ".cache", "kube-batch-tpu", "jax"
-        )
-        _os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        # persist any compile costing >= 0.5 s (the solve's bucket
-        # compiles are 10-30 s; sub-0.5s programs stay uncached — not
-        # worth the disk churn) regardless of program size
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:  # noqa: BLE001 -- cache is an optimization only
+    app_dir = getattr(jax.config, "jax_compilation_cache_dir", None)
+    if app_dir:  # an embedding application's own configuration
+        return app_dir
+    try:
+        _os.makedirs(_CHECKOUT_CACHE, exist_ok=True)
+    except OSError as e:  # read-only checkout: run uncached, say so
         import logging
 
-        logging.getLogger("kube_batch_tpu.ops").info(
-            "persistent jax compilation cache unavailable", exc_info=True
+        logging.getLogger("kube_batch_tpu.ops").warning(
+            "persistent jax compilation cache off: %s (set "
+            "JAX_COMPILATION_CACHE_DIR to a writable directory)", e
         )
+        return None
+    jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE)
+    # persist any compile costing >= 0.5 s (the solve's bucket compiles
+    # are seconds; sub-0.5s programs stay uncached — not worth the disk
+    # churn) regardless of program size
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    return _CHECKOUT_CACHE
 
 
 from kube_batch_tpu.ops.encode import EncodedSnapshot, encode_session  # noqa: E402

@@ -1,9 +1,9 @@
 """Incremental cross-cycle encode cache + device-resident tensor arena.
 
-BENCH_r05 showed ~27% of the flagship cycle's wall clock is host-side
-encode/replay work recomputed from scratch every session even though
-consecutive snapshots differ by a handful of pods/nodes. Production
-schedulers amortize exactly this (Kant keeps cluster state resident and
+Host-side encode/replay work is recomputed from scratch every session
+even though consecutive snapshots differ by a handful of pods/nodes
+(its share of the cycle on the chip is not measured on current code).
+Production schedulers amortize exactly this (Kant keeps cluster state resident and
 updates it event-driven; "Priority Matters" measures constraint/packing
 matrices as overwhelmingly stable across Kubernetes scheduling rounds).
 This module makes the encode cost scale with the *delta*:

@@ -21,7 +21,8 @@ Capacity therefore scales with mesh size: the per-shard VMEM claim is
 the node block only (`ops.pallas_solve.block_vmem_bytes`), so a
 snapshot that overflows `vmem_budget()` on one chip stays on the Pallas
 rung when `node_block_bytes / mesh_size` fits — instead of falling to
-the XLA twin (the 4.5s-vs-0.5s cliff BENCH_r05 measured at 50k x 5k).
+the XLA twin (the cliff between the rungs is not measured on current
+code).
 
 Block backends (``KBT_MESH_PALLAS`` or the ``block_impl`` argument):
 
@@ -241,11 +242,6 @@ def _blocked_programs(
     signature so the cross-tier resume protocol cannot drift."""
     import jax.numpy as jnp
     from jax import lax
-
-    try:  # jax >= 0.6 exports shard_map at the top level
-        from jax import shard_map  # type: ignore[attr-defined]
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
 
     mesh = Mesh(np.asarray(devices), (axis_name,))
     m = len(devices)
@@ -654,12 +650,12 @@ def _blocked_programs(
         }
         return rep_out, sh_out
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(), sh_specs),
         out_specs=(P(), out_sh_specs),
-        check_rep=False,
+        check_vma=False,
     )
 
     def run(a: dict, statics: dict, state: Optional[SolveState]) -> SolveState:

@@ -178,21 +178,27 @@ def test_supported_envelope_edges():
 
 
 def test_vmem_budget_is_device_aware(monkeypatch):
-    """v5e-class cores (128 MiB VMEM) get the wide budget — measured on
-    the bench chip: 400k x 40k (~33 MiB resident) compiles and runs —
-    while unknown cores keep the conservative default, and
-    KBT_VMEM_BUDGET overrides both."""
+    """v5e-class cores (128 MiB VMEM) get the wide budget, older TPU
+    cores the conservative one, a TPU missing from the table raises,
+    the CPU (interpret path) keeps the conservative value, and
+    KBT_VMEM_BUDGET overrides them all."""
     import jax
 
     from kube_batch_tpu.ops import pallas_solve
 
     class Dev:
-        def __init__(self, kind):
+        def __init__(self, kind, platform="tpu"):
             self.device_kind = kind
+            self.platform = platform
 
     monkeypatch.setattr(jax, "devices", lambda *a: [Dev("TPU v5 lite")])
     assert pallas_solve.vmem_budget() == 96 * 1024 * 1024
     monkeypatch.setattr(jax, "devices", lambda *a: [Dev("TPU v3")])
+    assert pallas_solve.vmem_budget() == pallas_solve._DEFAULT_VMEM_BUDGET
+    monkeypatch.setattr(jax, "devices", lambda *a: [Dev("TPU v99")])
+    with pytest.raises(ValueError, match="TPU v99"):
+        pallas_solve.vmem_budget()
+    monkeypatch.setattr(jax, "devices", lambda *a: [Dev("cpu", "cpu")])
     assert pallas_solve.vmem_budget() == pallas_solve._DEFAULT_VMEM_BUDGET
     monkeypatch.setenv("KBT_VMEM_BUDGET", str(7 * 1024 * 1024))
     assert pallas_solve.vmem_budget() == 7 * 1024 * 1024
