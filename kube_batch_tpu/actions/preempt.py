@@ -12,7 +12,7 @@ from __future__ import annotations
 
 from typing import Callable, Optional
 
-from kube_batch_tpu import log, metrics
+from kube_batch_tpu import log, metrics, obs
 from kube_batch_tpu.api.job_info import JobInfo, TaskInfo
 from kube_batch_tpu.api.node_info import NodeInfo
 from kube_batch_tpu.api.resource_info import Resource
@@ -64,10 +64,13 @@ def _preempt(
     preemptor: TaskInfo,
     filter_fn: Callable[[TaskInfo], bool],
     candidates_fn: CandidatesFn,
+    tally: list,
 ) -> bool:
-    """One preemptor against candidate nodes (preempt.go:176-256)."""
+    """One preemptor against candidate nodes (preempt.go:176-256);
+    ``tally`` accumulates [victims scanned, victims chosen]."""
     for node in candidates_fn(ssn, preemptor):
         preemptees = [task.clone() for task in node.tasks.values() if filter_fn(task)]
+        tally[0] += len(preemptees)
         victims = ssn.preemptable(preemptor, preemptees)
         metrics.update_preemption_victims_count(len(victims))
 
@@ -88,6 +91,7 @@ def _preempt(
                 preemptor.namespace, preemptor.name,
             )
             stmt.evict(preemptee, "preempt")
+            tally[1] += 1
             preempted.add(preemptee.resreq)
             if resreq.less_equal(preempted):
                 break
@@ -110,7 +114,11 @@ def run_preempt(
     statement_factory: StatementFactory = Statement,
     candidates_fn: CandidatesFn = serial_candidates,
 ) -> None:
-    """The full preempt pass (preempt.go:58-170)."""
+    """The full preempt pass (preempt.go:58-170). The current span (the
+    action's) carries ``victims_scanned`` (running tasks the filters
+    offered to Preemptable) and ``victims_chosen`` (evicted, committed
+    or not)."""
+    tally = [0, 0]
     preemptors_map: dict[str, PriorityQueue] = {}
     preemptor_tasks: dict[str, PriorityQueue] = {}
     under_request: list[JobInfo] = []
@@ -160,7 +168,7 @@ def run_preempt(
                         and preemptor.job != task.job
                     )
 
-                if _preempt(ssn, stmt, preemptor, job_filter, candidates_fn):
+                if _preempt(ssn, stmt, preemptor, job_filter, candidates_fn, tally):
                     assigned = True
 
                 if ssn.job_pipelined(preemptor_job):
@@ -193,10 +201,17 @@ def run_preempt(
                     return preemptor.job == task.job
 
                 stmt = statement_factory(ssn)
-                assigned = _preempt(ssn, stmt, preemptor, intra_job_filter, candidates_fn)
+                assigned = _preempt(
+                    ssn, stmt, preemptor, intra_job_filter, candidates_fn, tally
+                )
                 stmt.commit()
                 if not assigned:
                     break
+
+    span = obs.current()
+    if span is not None:
+        span.set_attr("victims_scanned", tally[0])
+        span.set_attr("victims_chosen", tally[1])
 
 
 class PreemptAction(Action):

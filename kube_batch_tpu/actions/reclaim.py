@@ -12,6 +12,7 @@ from __future__ import annotations
 
 from typing import Callable, Optional
 
+from kube_batch_tpu import obs
 from kube_batch_tpu.api.job_info import TaskInfo
 from kube_batch_tpu.api.node_info import NodeInfo
 from kube_batch_tpu.api.resource_info import Resource
@@ -41,7 +42,10 @@ def run_reclaim(
     feasible_fn: FeasibleFn = serial_feasible,
     on_pipeline: Optional[Callable[[TaskInfo, str], None]] = None,
 ) -> None:
-    """The full reclaim pass (reclaim.go:54-186)."""
+    """The full reclaim pass (reclaim.go:54-186). The current span (the
+    action's) carries ``victims_scanned`` (other queues' running tasks
+    offered to Reclaimable) and ``victims_chosen`` (evicted)."""
+    scanned = chosen = 0
     queues = PriorityQueue(ssn.queue_order_fn)
     seen_queues: set[str] = set()
     preemptors_map: dict[str, PriorityQueue] = {}
@@ -94,6 +98,7 @@ def run_reclaim(
                     continue
                 if resident_job.queue != job.queue:
                     reclaimees.append(resident.clone())
+            scanned += len(reclaimees)
             victims = ssn.reclaimable(task, reclaimees)
             if not victims:
                 continue
@@ -109,6 +114,7 @@ def run_reclaim(
                     ssn.evict(reclaimee, "reclaim")
                 except Exception:
                     continue
+                chosen += 1
                 reclaimed.add(reclaimee.resreq)
                 if resreq.less_equal(reclaimed):
                     break
@@ -122,6 +128,11 @@ def run_reclaim(
 
         if assigned:
             queues.push(queue)
+
+    span = obs.current()
+    if span is not None:
+        span.set_attr("victims_scanned", scanned)
+        span.set_attr("victims_chosen", chosen)
 
 
 class ReclaimAction(Action):

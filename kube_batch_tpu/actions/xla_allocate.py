@@ -42,8 +42,11 @@ values — and logs that it did so.
 
 from __future__ import annotations
 
+import contextvars
+import dataclasses
 import logging
 import os
+from typing import Optional
 
 import jax  # noqa: F401  -- fail registration, not mid-cycle, when absent
 import numpy as np
@@ -321,7 +324,7 @@ class XlaAllocateAction(Action):
 
             compile0 = compile_count()
         try:
-            with sspan, obs.annotate("kbt.solve"):
+            with sspan:
                 state = solve_fn(None)
                 while int(state.paused_at) >= 0:
                     if budget is not None:
@@ -414,12 +417,13 @@ class XlaAllocateAction(Action):
         }
         self.last_timings = timings
 
-        def _post_solve(parent=None) -> float:
+        def _post_solve() -> float:
+            # replay, explain and dispatch are siblings under the action
+            # span: replay is the session's own work (apply + the gang
+            # barrier), dispatch the store's
             t0 = _time.perf_counter()
             t_explain = 0.0
-            with obs.span(
-                "gang.assign", parent=parent, assigned=int(result.n_assigned)
-            ):
+            with obs.span("replay") as rspan:
                 replay.apply_upto(assign_pos, assigned_node, assigned_kind, int(result.n_assigned))
                 if not defer and budget is not None:
                     # The last pre-dispatch gate: past this point binds reach
@@ -427,22 +431,26 @@ class XlaAllocateAction(Action):
                     # cycle.overrun drill injects here (inject=True) — maximal
                     # discardable work, zero cache mutation.
                     budget.check("dispatch barrier", inject=True)
-                # Post-solve forensics (obs/explain): batched plane/score
-                # reductions against the FINAL solver state, published before
-                # replay.finish so the journal intents it writes can attach
-                # per-gang reason payloads — and after the budget gate, so an
-                # aborted cycle leaves no half-cycle records behind.
-                from kube_batch_tpu.obs import explain as _explain
+                plan = replay.finish(np.asarray(result.ready_cnt))
+                if rspan is not obs.NOOP_SPAN:
+                    rspan.set_attr("gangs", len({t.job for t in plan.tasks}))
+                    rspan.set_attr("tasks", len(plan.tasks))
+            # Post-solve forensics (obs/explain): batched plane/score
+            # reductions against the FINAL solver state, published before
+            # the dispatch so the journal intents it writes can attach
+            # per-gang reason payloads — and after the budget gate, so an
+            # aborted cycle leaves no half-cycle records behind.
+            from kube_batch_tpu.obs import explain as _explain
 
-                if _explain.enabled():
-                    te = _time.perf_counter()
-                    with obs.span("explain", micro=micro) as xsp:
-                        recs = _explain.explain_post_solve(ssn, enc, arrays, state, result)
-                        _explain.publish(ssn, recs)
-                        for k, v in _explain.summary(recs).items():
-                            xsp.set_attr(k, v)
-                    t_explain = _time.perf_counter() - te
-                replay.finish(np.asarray(result.ready_cnt))
+            if _explain.enabled():
+                te = _time.perf_counter()
+                with obs.span("explain", micro=micro) as xsp:
+                    recs = _explain.explain_post_solve(ssn, enc, arrays, state, result)
+                    _explain.publish(ssn, recs)
+                    for k, v in _explain.summary(recs).items():
+                        xsp.set_attr(k, v)
+                t_explain = _time.perf_counter() - te
+            replay.dispatch(plan)
             dur = _time.perf_counter() - t0
             timings["replay_s"] = dur - t_explain
             if t_explain:
@@ -450,14 +458,16 @@ class XlaAllocateAction(Action):
             return dur
 
         if defer:
-            ctx = obs.current()  # pool threads don't inherit the contextvar
+            # pool threads don't inherit the contextvar: the post-solve
+            # spans run under this action's captured context
+            ctx = contextvars.copy_context()
 
             def _deferred() -> None:
                 # stamp the dispatch window for the measured overlap
                 # fraction: [d0, d1] intersected with the consumer's
                 # join window is the serialized share
                 d0 = _time.perf_counter()
-                _post_solve(parent=ctx)
+                ctx.run(_post_solve)
                 _pipeline.fence.record_dispatch_window(d0, _time.perf_counter())
 
             fut = _pipeline.submit(ssn.cache, _deferred)
@@ -1451,10 +1461,10 @@ class _Replayer:
             if debug_on:
                 log.debug("dispatched gang job %s (%d tasks)", job.uid, ready_cnt_l[i])
 
-    def finish(self, ready_cnt) -> None:
-        """Final share sync + the gang dispatch barrier."""
-        from kube_batch_tpu import metrics
-
+    def finish(self, ready_cnt) -> "_Dispatch":
+        """Final share sync + the gang dispatch barrier: the gangs that
+        reached minMember flip to Binding and come back, with their bind
+        columns, for :meth:`dispatch`."""
         ssn = self.ssn
         if self.drf is not None:
             drf = self.drf
@@ -1602,42 +1612,64 @@ class _Replayer:
                     for t, r in zip(to_bind, rows_b.tolist())
                 ]
                 hostnames = [t.node_name for t in to_bind]
+        return _Dispatch(to_bind, hostnames, keys, rows_b, created)
+
+    def dispatch(self, plan: "_Dispatch") -> None:
+        """The store side of :meth:`finish`'s barrier: bulk-bind the
+        dispatched tasks, then record their scheduling latencies."""
+        to_bind, hostnames, keys = plan.tasks, plan.hostnames, plan.keys
+        if not to_bind:
+            return
+        cache = self.ssn.cache
         # Bulk bind: one cache mutex acquisition + one async write batch
         # for the whole action's dispatches (the replay-diet half of
         # VERDICT r3 item 8 — per-task cache.bind was the replay's
         # single largest cost at 50k).
-        if to_bind:
-            keyed_bind = getattr(ssn.cache, "bind_many_keyed", None)
-            bind_many = getattr(ssn.cache, "bind_many", None)
-            if keyed_bind is not None:
-                # parallel-list form: no 200k (task, host) tuple builds
-                keyed_bind(to_bind, hostnames, keys)
-            elif bind_many is not None:
-                pairs = list(zip(to_bind, hostnames))
-                if _accepts_keys(bind_many):
-                    bind_many(pairs, keys=keys)
-                else:
-                    bind_many(pairs)
+        keyed_bind = getattr(cache, "bind_many_keyed", None)
+        bind_many = getattr(cache, "bind_many", None)
+        if keyed_bind is not None:
+            # parallel-list form: no 200k (task, host) tuple builds
+            keyed_bind(to_bind, hostnames, keys)
+        elif bind_many is not None:
+            pairs = list(zip(to_bind, hostnames))
+            if _accepts_keys(bind_many):
+                bind_many(pairs, keys=keys)
             else:
-                for t, h in zip(to_bind, hostnames):
-                    ssn.cache.bind(t, h)
-        if to_bind:
-            # e2e scheduling latency per dispatched pod, as one vector op
-            # instead of a 50k-iteration max() loop. Each task's latency
-            # ends at ITS solve segment's completion (decided_at), not at
-            # one post-replay batch timestamp (reference metrics.go:66-72
-            # stamps per task at dispatch). A gang can also carry tasks a
-            # PRIOR action allocated (e.g. serial allocate earlier in the
-            # actions string) that this encode never saw — those stamp at
-            # dispatch time, exactly as the serial path would have.
-            import time as _time
+                bind_many(pairs)
+        else:
+            for t, h in zip(to_bind, hostnames):
+                cache.bind(t, h)
+        # e2e scheduling latency per dispatched pod, as one vector op
+        # instead of a 50k-iteration max() loop. Each task's latency
+        # ends at ITS solve segment's completion (decided_at), not at
+        # one post-replay batch timestamp (reference metrics.go:66-72
+        # stamps per task at dispatch). A gang can also carry tasks a
+        # PRIOR action allocated (e.g. serial allocate earlier in the
+        # actions string) that this encode never saw — those stamp at
+        # dispatch time, exactly as the serial path would have.
+        import time as _time
 
-            decided = np.where(
-                rows_b >= 0, self.decided_at[np.maximum(rows_b, 0)], _time.time()
-            )
-            metrics.update_task_schedule_durations(
-                np.maximum(0.0, decided - created)
-            )
+        rows_b = plan.rows
+        decided = np.where(
+            rows_b >= 0, self.decided_at[np.maximum(rows_b, 0)], _time.time()
+        )
+        metrics.update_task_schedule_durations(
+            np.maximum(0.0, decided - plan.created)
+        )
+
+
+@dataclasses.dataclass
+class _Dispatch:
+    """What :meth:`_Replayer.finish` hands to :meth:`_Replayer.dispatch`:
+    the dispatched tasks in dispatch order with their bind columns (host
+    names, store keys, encode rows, creation stamps; None when nothing
+    was dispatched)."""
+
+    tasks: list
+    hostnames: Optional[list]
+    keys: Optional[list]
+    rows: Optional[np.ndarray]
+    created: Optional[np.ndarray]
 
 
 def _accepts_keys(bind_many) -> bool:

@@ -30,7 +30,14 @@ checked only by grep and luck:
 - **span names**: the literal first argument of every ``obs.span(...)``
   / ``obs.emit(...)`` call must be declared in ``obs.SPAN_NAMES``
   (R007) — a typo'd name silently forks the trace tree — and every
-  declared name must have a call site (R008).
+  declared name must have a call site (R008). A name built from a
+  constant prefix (``"action." + action.name``) is a wildcard that must
+  match a declared name and credits every name it matches. The
+  ``action.*`` family is checked against the action registry (the
+  ``register_action(<module>.new())`` calls in actions/factory.py) in
+  both directions: a registered action without its declared span is
+  R007 (the scheduler opens it every cycle that runs the action), a
+  declared ``action.<name>`` naming no registered action is R008.
 - **debug endpoints**: every ``/debug/*`` route literal in server.py
   must be declared in ``obs.DEBUG_ENDPOINTS`` and vice versa (R009 —
   an undeclared route escapes the contract, a declared-but-unserved
@@ -64,6 +71,8 @@ FAULTS_MODULE = "kube_batch_tpu/faults/__init__.py"
 METRICS_MODULE = "kube_batch_tpu/metrics/__init__.py"
 OBS_MODULE = "kube_batch_tpu/obs/__init__.py"
 SERVER_MODULE = "kube_batch_tpu/server.py"
+ACTION_FACTORY = "kube_batch_tpu/actions/factory.py"
+ACTION_SPAN_PREFIX = "action."
 RUNBOOK = "deployment/README.md"
 
 _ENV_RE = re.compile(r"^KBT_[A-Z0-9_]+$")
@@ -101,11 +110,10 @@ def _declared_points(files: list[SourceFile]) -> dict[str, int]:
     return {}
 
 
-def _point_arg(call: ast.Call) -> Optional[tuple[str, bool]]:
-    """(name-or-pattern, is_pattern) for the call's first argument."""
-    if not call.args:
-        return None
-    a = call.args[0]
+def _name_arg(a: ast.expr) -> Optional[tuple[str, bool]]:
+    """(name-or-pattern, is_pattern) for a registry name passed as an
+    argument: a literal, an f-string (each placeholder a ``*``), or a
+    constant prefix joined to a runtime value (``"action." + name``)."""
     if isinstance(a, ast.Constant) and isinstance(a.value, str):
         return a.value, False
     if isinstance(a, ast.JoinedStr):
@@ -117,7 +125,19 @@ def _point_arg(call: ast.Call) -> Optional[tuple[str, bool]]:
                 parts.append("*")
         pattern = "".join(parts)
         return pattern, True
+    if (
+        isinstance(a, ast.BinOp)
+        and isinstance(a.op, ast.Add)
+        and isinstance(a.left, ast.Constant)
+        and isinstance(a.left.value, str)
+    ):
+        return a.left.value + "*", True
     return None  # a variable — not statically checkable
+
+
+def _point_arg(call: ast.Call) -> Optional[tuple[str, bool]]:
+    """(name-or-pattern, is_pattern) for the call's first argument."""
+    return _name_arg(call.args[0]) if call.args else None
 
 
 def _check_fault_points(files: list[SourceFile], findings: list[Finding]) -> None:
@@ -323,6 +343,76 @@ def _declared_str_tuple(
     return {}
 
 
+def _registered_actions(files: list[SourceFile]) -> dict[str, tuple[str, int]]:
+    """action name -> (module path, lineno of its ``name``) for every
+    ``register_action(<module>.new())`` in actions/factory.py, the name
+    read from the module's ``name`` property returning a literal."""
+    modules: set[str] = set()
+    for sf in files:
+        if sf.path != ACTION_FACTORY:
+            continue
+        for node in ast.walk(sf.tree):
+            if (
+                isinstance(node, ast.Call)
+                and isinstance(node.func, ast.Name)
+                and node.func.id == "register_action"
+                and node.args
+                and isinstance(node.args[0], ast.Call)
+                and isinstance(node.args[0].func, ast.Attribute)
+                and isinstance(node.args[0].func.value, ast.Name)
+            ):
+                modules.add(node.args[0].func.value.id)
+    paths = {f"kube_batch_tpu/actions/{m}.py" for m in modules}
+    out: dict[str, tuple[str, int]] = {}
+    for sf in files:
+        if sf.path not in paths:
+            continue
+        for node in ast.walk(sf.tree):
+            if isinstance(node, ast.FunctionDef) and node.name == "name":
+                for sub in ast.walk(node):
+                    if (
+                        isinstance(sub, ast.Return)
+                        and isinstance(sub.value, ast.Constant)
+                        and isinstance(sub.value.value, str)
+                    ):
+                        out.setdefault(sub.value.value, (sf.path, sub.lineno))
+    return out
+
+
+def _check_action_spans(
+    files: list[SourceFile], declared: dict[str, int], findings: list[Finding]
+) -> None:
+    """The ``action.*`` span family against the action registry, both
+    directions."""
+    registered = _registered_actions(files)
+    if not registered:
+        return
+    for action, (path, lineno) in sorted(registered.items()):
+        if ACTION_SPAN_PREFIX + action not in declared:
+            findings.append(
+                Finding(
+                    path, lineno, "KBT-R007",
+                    f"action {action!r} is registered but span "
+                    f"'{ACTION_SPAN_PREFIX}{action}' is not declared in "
+                    "obs.SPAN_NAMES — every cycle running it opens an "
+                    "undeclared span",
+                    symbol=f"span:{ACTION_SPAN_PREFIX}{action}",
+                )
+            )
+    for span_name, lineno in sorted(declared.items()):
+        if not span_name.startswith(ACTION_SPAN_PREFIX):
+            continue
+        if span_name[len(ACTION_SPAN_PREFIX):] not in registered:
+            findings.append(
+                Finding(
+                    OBS_MODULE, lineno, "KBT-R008",
+                    f"span name {span_name!r} names no registered action — "
+                    "no cycle can open it",
+                    symbol=f"span:{span_name}",
+                )
+            )
+
+
 def _check_span_names(files: list[SourceFile], findings: list[Finding]) -> None:
     declared = _declared_str_tuple(files, OBS_MODULE, "SPAN_NAMES")
     if not declared:
@@ -342,15 +432,27 @@ def _check_span_names(files: list[SourceFile], findings: list[Finding]) -> None:
                 continue
             if not node.args:
                 continue
-            a = node.args[0]
-            if not (isinstance(a, ast.Constant) and isinstance(a.value, str)):
-                continue  # a variable (or m.span(1)) — not checkable
-            span_name = a.value
+            got = _name_arg(node.args[0])
+            if got is None:
+                continue
+            span_name, is_pattern = got
             if name == "span" and isinstance(fn, ast.Attribute) and _attr_root(
                 fn
             ) not in ("obs", ""):
                 continue  # e.g. some_match.span("x") on a non-obs object
-            if span_name in declared:
+            if is_pattern:
+                hits = [d for d in declared if fnmatchcase(d, span_name)]
+                used.update(hits)
+                if not hits:
+                    findings.append(
+                        Finding(
+                            sf.path, node.lineno, "KBT-R007",
+                            f"dynamic span name pattern {span_name!r} matches "
+                            "no name in obs.SPAN_NAMES",
+                            symbol=f"span:{span_name}",
+                        )
+                    )
+            elif span_name in declared:
                 used.add(span_name)
             else:
                 findings.append(
@@ -373,6 +475,7 @@ def _check_span_names(files: list[SourceFile], findings: list[Finding]) -> None:
                     symbol=f"span:{span_name}",
                 )
             )
+    _check_action_spans(files, declared, findings)
 
 
 def _server_debug_routes(files: list[SourceFile]) -> dict[str, int]:

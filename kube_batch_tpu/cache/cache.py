@@ -557,6 +557,13 @@ class SchedulerCache:
         self._workers: list[threading.Thread] = []
         self._stop = threading.Event()
         self._synced = False
+        # Ingest tallies: (kind, verb) -> [events, seconds, events
+        # published, seconds published]. Handlers add to them in place
+        # and publish_ingest() folds the difference into the exported
+        # counters once per snapshot, so no event takes a metrics lock.
+        # Events reach the handlers one at a time (the store delivers
+        # under its dispatch lock, a watch-fed backend from its pump).
+        self._ingest: dict[tuple[str, str], list] = {}
 
         self._subscribe()
 
@@ -571,56 +578,61 @@ class SchedulerCache:
 
     def _subscribe(self) -> None:
         s = self.store
-        s.add_event_handler(
-            PODS,
-            EventHandler(
-                on_add=self.add_pod,
-                on_update=self.update_pod,
-                on_delete=self.delete_pod,
-                filter=self._pod_filter,
-            ),
-        )
-        s.add_event_handler(
-            NODES,
-            EventHandler(
-                on_add=self.add_node,
-                on_update=self.update_node,
-                on_delete=self.delete_node,
-            ),
-        )
-        s.add_event_handler(
-            POD_GROUPS,
-            EventHandler(
-                on_add=self.add_pod_group,
-                on_update=self.update_pod_group,
-                on_delete=self.delete_pod_group,
-            ),
-        )
-        s.add_event_handler(
-            QUEUES,
-            EventHandler(
-                on_add=self.add_queue,
-                on_update=self.update_queue,
-                on_delete=self.delete_queue,
-            ),
-        )
-        s.add_event_handler(
-            PDBS,
-            EventHandler(
-                on_add=self.add_pdb,
-                on_update=self.update_pdb,
-                on_delete=self.delete_pdb,
-            ),
-        )
-        s.add_event_handler(
-            PRIORITY_CLASSES,
-            EventHandler(
-                on_add=self.add_priority_class,
-                on_update=self.update_priority_class,
-                on_delete=self.delete_priority_class,
-            ),
-        )
+        for kind, label, add, update, delete, filt in (
+            (PODS, "pod", self.add_pod, self.update_pod, self.delete_pod,
+             self._pod_filter),
+            (NODES, "node", self.add_node, self.update_node, self.delete_node, None),
+            (POD_GROUPS, "podgroup", self.add_pod_group, self.update_pod_group,
+             self.delete_pod_group, None),
+            (QUEUES, "queue", self.add_queue, self.update_queue, self.delete_queue,
+             None),
+            (PDBS, "pdb", self.add_pdb, self.update_pdb, self.delete_pdb, None),
+            (PRIORITY_CLASSES, "priorityclass", self.add_priority_class,
+             self.update_priority_class, self.delete_priority_class, None),
+        ):
+            s.add_event_handler(
+                kind,
+                EventHandler(
+                    on_add=self._counted(label, "add", add),
+                    on_update=self._counted(label, "update", update),
+                    on_delete=self._counted(label, "delete", delete),
+                    filter=filt,
+                ),
+            )
         self._synced = True
+
+    def _counted(self, kind: str, verb: str, handler):
+        """``handler`` behind its ingest tally: one increment per event,
+        and the handler's seconds only while tracing is on."""
+        tally = self._ingest[(kind, verb)] = [0, 0.0, 0, 0.0]
+        clock = time.perf_counter
+
+        def counted(*args) -> None:
+            tally[0] += 1
+            if not obs.enabled():
+                handler(*args)
+                return
+            t0 = clock()
+            try:
+                handler(*args)
+            finally:
+                tally[1] += clock() - t0
+
+        return counted
+
+    def publish_ingest(self) -> None:
+        """Fold the event handlers' tallies since the last call into
+        ``cache_events_total`` / ``cache_event_seconds_total``
+        ({kind, verb}); snapshot() calls it once per cycle."""
+        for (kind, verb), tally in self._ingest.items():
+            events, seconds = tally[0], tally[1]
+            if events == tally[2] and seconds == tally[3]:
+                continue
+            labels = {"kind": kind, "verb": verb}
+            metrics.cache_events.inc(labels, by=events - tally[2])
+            if seconds > tally[3]:
+                metrics.cache_event_seconds.inc(labels, by=seconds - tally[3])
+            tally[2], tally[3] = events, seconds
 
     def run(self) -> None:
         """Start the resync + GC workers and the async write pool
@@ -1490,6 +1502,7 @@ class SchedulerCache:
     # -- snapshot (reference cache.go:535-585) -----------------------------
 
     def snapshot(self) -> ClusterInfo:
+        self.publish_ingest()
         reset = getattr(self.volume_binder, "reset", None)
         if reset is not None:
             reset()  # assumptions never outlive a session (see reset())
@@ -1498,26 +1511,34 @@ class SchedulerCache:
             # Stamp the store version this snapshot solves over — every
             # conditional dispatch until the next snapshot carries it.
             self._snapshot_version = getattr(self.store, "version", 0)
-            for name, node in self.nodes.items():
-                snapshot.nodes[name] = node.clone()
+            with obs.span("snapshot.nodes") as sp:
+                for name, node in self.nodes.items():
+                    snapshot.nodes[name] = node.clone()
+                if sp is not obs.NOOP_SPAN:
+                    sp.set_attr("objects", len(snapshot.nodes))
+                    sp.set_attr("tasks", sum(len(n.tasks) for n in snapshot.nodes.values()))
             for name, q in self.queues.items():
                 snapshot.queues[name] = q.clone()
-            for uid, job in self.jobs.items():
-                if job.pod_group is None and job.pdb is None:
-                    log.V(4).infof("Job <%s> has no scheduling spec, ignored", uid)
-                    continue
-                if job.queue not in snapshot.queues:
-                    log.V(3).infof(
-                        "Queue <%s> of job <%s/%s> does not exist, ignored",
-                        job.queue, job.namespace, job.name,
-                    )
-                    continue
-                if job.pod_group is not None:
-                    job.priority = self._default_priority
-                    pc = self.priority_classes.get(job.pod_group.spec.priority_class_name)
-                    if pc is not None:
-                        job.priority = pc.value
-                snapshot.jobs[uid] = job.clone()
+            with obs.span("snapshot.jobs") as sp:
+                for uid, job in self.jobs.items():
+                    if job.pod_group is None and job.pdb is None:
+                        log.V(4).infof("Job <%s> has no scheduling spec, ignored", uid)
+                        continue
+                    if job.queue not in snapshot.queues:
+                        log.V(3).infof(
+                            "Queue <%s> of job <%s/%s> does not exist, ignored",
+                            job.queue, job.namespace, job.name,
+                        )
+                        continue
+                    if job.pod_group is not None:
+                        job.priority = self._default_priority
+                        pc = self.priority_classes.get(job.pod_group.spec.priority_class_name)
+                        if pc is not None:
+                            job.priority = pc.value
+                    snapshot.jobs[uid] = job.clone()
+                if sp is not obs.NOOP_SPAN:
+                    sp.set_attr("objects", len(snapshot.jobs))
+                    sp.set_attr("tasks", sum(len(j.tasks) for j in snapshot.jobs.values()))
             log.V(3).infof(
                 "Snapshot: %d jobs, %d queues, %d nodes",
                 len(snapshot.jobs), len(snapshot.queues), len(snapshot.nodes),

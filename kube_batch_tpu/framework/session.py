@@ -507,6 +507,11 @@ def open_session(
     (kube_batch_tpu.streaming); everything downstream (plugin
     registration, JobValid gate, actions, close_session) is identical to
     a full cycle."""
+    with obs.span("session.open"):
+        return _open_session(cache, tiers, action_arguments, world)
+
+
+def _open_session(cache, tiers, action_arguments, world) -> Session:
     ssn = Session(cache)
     ssn.tiers = tiers
     ssn.action_arguments = action_arguments or {}
@@ -570,6 +575,11 @@ def close_session(ssn: Session, discard: bool = False) -> None:
     skipped: the aborted cycle's session state is rolled back wholesale
     — Statement.discard at cycle granularity — leaving the cache/store
     byte-identical to the cycle's start."""
+    with obs.span("session.close"):
+        _close_session(ssn, discard)
+
+
+def _close_session(ssn: Session, discard: bool) -> None:
     # Pipelined cycles (KBT_PIPELINE): a deferred post-solve dispatch
     # must land before anything below — the plugin close hooks and the
     # commit write-back read the session state the deferred replay

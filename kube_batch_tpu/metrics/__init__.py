@@ -324,6 +324,18 @@ watch_relists = Counter(
     "Full re-lists performed by watch clients after 410-Gone, by kind",
 )
 
+# -- cache ingest (kube_batch_tpu.cache.SchedulerCache event handlers) ------
+cache_events = Counter(
+    f"{_SUBSYSTEM}_cache_events_total",
+    "Store events the scheduler cache's handlers processed, by kind and verb "
+    "(folded in once per snapshot)",
+)
+cache_event_seconds = Counter(
+    f"{_SUBSYSTEM}_cache_event_seconds_total",
+    "Seconds the scheduler cache's event handlers took, by kind and verb "
+    "(timed only while tracing is on; folded in once per snapshot)",
+)
+
 # -- incremental encode cache (kube_batch_tpu.ops.encode_cache) --------------
 encode_cache_hits = Counter(
     f"{_SUBSYSTEM}_encode_cache_hits_total",
@@ -992,6 +1004,8 @@ def render_prometheus_text() -> str:
         stale_cycles_skipped,
         watch_snapshot_age,
         watch_relists,
+        cache_events,
+        cache_event_seconds,
         encode_cache_hits,
         encode_cache_invalidations,
         encode_warm_fraction,

@@ -5,10 +5,15 @@ answer "how slow"; this package answers "where did gang X's 40 ms go,
 on which shard, at which solver tier, behind which conflict retry":
 
 - **Spans.** A scheduling cycle opens a root span (``cycle`` /
-  ``micro_cycle``) with children for snapshot, encode (cache hit/warm
-  stats as attrs), solve (tier + mesh size, compile events), statement
-  commit, journal append, and store dispatch — each gang bind a span of
-  its own carrying every conflict retry as a span event. Trace context
+  ``micro_cycle``) whose children cover the whole cycle: session open
+  (the snapshot, split into nodes and jobs), one span per action, and
+  session close (statement commit). Inside ``xla_allocate``: encode
+  (cache hit/warm stats as attrs), solve (tier + mesh size, compile
+  events), replay, and store dispatch with its journal append — each
+  gang bind a span of its own carrying every conflict retry as a span
+  event. While tracing is on every span is also a ``jax.profiler``
+  annotation named ``kbt.<span>``, so a device profile carries the
+  program's own layers on its clock. Trace context
   crosses process boundaries as two ``/backend/v1/`` HTTP headers
   (:data:`HDR_TRACE`/:data:`HDR_SPAN`), so a federated bind's
   conflict-retry loop is ONE trace spanning N schedulers and the store
@@ -45,6 +50,7 @@ from __future__ import annotations
 
 import collections
 import contextvars
+import functools
 import json
 import math
 import os
@@ -74,7 +80,6 @@ __all__ = [
     "current",
     "current_headers",
     "from_headers",
-    "annotate",
     "FlightRecorder",
     "recorder",
     "QuantileSketch",
@@ -104,10 +109,25 @@ HDR_SPAN = "X-KBT-Span-Id"
 SPAN_NAMES = (
     "cycle",          # scheduler.run_once root
     "micro_cycle",    # scheduler.run_micro root (streaming)
+    "session.open",   # open_session: snapshot, plugin open hooks, JobValid gate
     "snapshot",       # session open: cache snapshot/clone
+    "snapshot.nodes", # snapshot: node clones (objects, resident tasks as attrs)
+    "snapshot.jobs",  # snapshot: job clones (objects, tasks as attrs)
+    # one span per action the scheduler runs, "action." + the registered
+    # action's name (KBT-R007/R008 check this family against the registry)
+    "action.enqueue",
+    "action.allocate",
+    "action.backfill",
+    "action.preempt",
+    "action.reclaim",
+    "action.xla_allocate",
+    "action.xla_backfill",
+    "action.xla_preempt",
+    "action.xla_reclaim",
     "encode",         # SoA encode (cache hit/warm stats as attrs)
     "solve",          # solver entry (tier, mesh size, compile events)
-    "gang.assign",    # one solved gang's host-side assignment/replay
+    "replay",         # xla_allocate: solved assignments into the session, up to dispatch
+    "session.close",  # close_session: plugin close hooks + commit
     "commit",         # statement commit at session close
     "journal.append", # write-intent journal append (seqs as attr)
     "dispatch",       # cache.bind_many host side: resolve+journal+submit
@@ -179,13 +199,27 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
+@functools.cache
+def _annotation_cls():
+    """``jax.profiler.TraceAnnotation``, imported on first use; None
+    where the profiler is unavailable (spans then carry no annotation)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
 class Span:
     """One timed, attributed node of a trace tree; a context manager
-    that makes itself the thread/task-current span for its extent."""
+    that makes itself the thread/task-current span for its extent and,
+    for the same extent, opens the ``jax.profiler`` annotation
+    ``kbt.<name>`` on its thread — so the span sits on the device
+    profile's clock too."""
 
     __slots__ = (
         "name", "trace_id", "span_id", "parent_id",
-        "start", "end", "attrs", "events", "tid", "_token",
+        "start", "end", "attrs", "events", "tid", "_token", "_annotation",
     )
 
     def __init__(
@@ -205,6 +239,7 @@ class Span:
         self.events: list[tuple[str, float, dict]] = []
         self.tid = threading.get_ident() & 0x7FFFFFFF
         self._token = None
+        self._annotation = None
 
     def set_attr(self, key: str, value) -> None:
         self.attrs[key] = value
@@ -214,9 +249,16 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = _current.set(self)
+        cls = _annotation_cls()
+        if cls is not None:
+            self._annotation = cls("kbt." + self.name)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         if self._token is not None:
             _current.reset(self._token)
             self._token = None
@@ -271,7 +313,8 @@ def span(name: str, parent=None, **attrs):
 def emit(name: str, start: float, end: float, parent=None, **attrs) -> None:
     """Record an already-elapsed interval as a finished span (e.g. a
     streaming time-to-bind measured between two watch events).
-    ``start``/``end`` are ``time.perf_counter()`` stamps."""
+    ``start``/``end`` are ``time.perf_counter()`` stamps. Never entered,
+    so it carries no profiler annotation."""
     if not _enabled:
         return
     s = span(name, parent=parent, **attrs)
@@ -331,20 +374,6 @@ def from_headers(headers) -> tuple[str, str] | None:
     if not tid:
         return None
     return (str(tid), str(sid or ""))
-
-
-def annotate(label: str):
-    """A ``jax.profiler`` trace annotation for a solver entry, so
-    device profiles line up with scheduler spans; no-op when tracing is
-    off or the profiler is unavailable."""
-    if not _enabled:
-        return NOOP_SPAN
-    try:
-        from jax.profiler import TraceAnnotation
-
-        return TraceAnnotation(label)
-    except Exception:  # noqa: BLE001 - profiler is best-effort
-        return NOOP_SPAN
 
 
 # -- flight recorder ---------------------------------------------------------
@@ -837,7 +866,7 @@ def install_signal_dump() -> bool:
 
 
 # The vectorized pipeline, so the smoke exercises the full span tree:
-# encode/solve/gang.assign come from xla_allocate, and dispatch goes
+# encode/solve/replay come from xla_allocate, and dispatch goes
 # through bind_many -> _do_bind_gang (the conditional per-gang
 # transaction whose conflict retries the smoke asserts on). The classic
 # `allocate` action binds per task and never takes that path. No

@@ -22,6 +22,7 @@ Divergences from the reference, by design:
 
 from __future__ import annotations
 
+import contextvars
 import threading
 import time
 from typing import Optional
@@ -366,7 +367,8 @@ class Scheduler:
                 for action in self.actions:
                     try:
                         action_start = time.perf_counter()
-                        action.execute(ssn)
+                        with obs.span("action." + action.name):
+                            action.execute(ssn)
                         metrics.update_action_duration(
                             action.name, time.perf_counter() - action_start
                         )
@@ -475,7 +477,8 @@ class Scheduler:
                         if ssn.deferred_dispatch is not None:
                             pipeline.join_session(ssn)
                         action_start = time.perf_counter()
-                        action.execute(ssn)
+                        with obs.span("action." + action.name):
+                            action.execute(ssn)
                         metrics.update_action_duration(
                             action.name, time.perf_counter() - action_start
                         )
@@ -532,11 +535,16 @@ class Scheduler:
         path would have surfaced it through run()'s catch-log)."""
         stream_state = self._stream_state
 
+        # the pool thread has no ambient span: close the session under
+        # the cycle's context so session.close stays in the cycle's trace
+        ctx = contextvars.copy_context()
+
         def _finish(_fut) -> None:
             try:
                 if stream_state is not None:
                     stream_state.adopt_full_cycle(ssn, aborted=False)
-                close_session(ssn)  # joins the (now done) deferred future
+                # joins the (now done) deferred future
+                ctx.run(close_session, ssn)
                 metrics.update_e2e_duration(time.perf_counter() - cycle_start)
                 metrics.schedule_attempts.inc()
                 if detector is not None:

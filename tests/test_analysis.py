@@ -239,6 +239,65 @@ def test_registry_fault_points_both_directions(tmp_path):
     assert [f.symbol for f in by_code["KBT-R003"]] == ["metric:register_nonexistent"]
 
 
+SPANS_FIXTURE = (
+    "SPAN_NAMES = (\n"
+    '    "cycle",\n'
+    '    "action.alpha",\n'
+    '    "action.ghost",\n'
+    ")\n"
+)
+
+ACTION_FACTORY_FIXTURE = '''
+from kube_batch_tpu.framework.registry import register_action
+
+def register_all_actions():
+    from kube_batch_tpu.actions import alpha, beta
+    register_action(alpha.new())
+    register_action(beta.new())
+'''
+
+ACTION_FIXTURE = '''
+class {cls}:
+    @property
+    def name(self):
+        return "{name}"
+
+def new():
+    return {cls}()
+'''
+
+LOOP_FIXTURE = '''
+from kube_batch_tpu import obs
+
+def run_once(actions, stage):
+    with obs.span("cycle"):
+        for action in actions:
+            with obs.span("action." + action.name):   # family: credits action.*
+                pass
+        with obs.span(f"stage.{stage}"):               # VIOLATION R007: matches nothing
+            pass
+'''
+
+
+def test_registry_action_spans_both_directions(tmp_path):
+    files = [
+        sf("kube_batch_tpu/obs/__init__.py", SPANS_FIXTURE),
+        sf("kube_batch_tpu/actions/factory.py", ACTION_FACTORY_FIXTURE),
+        sf("kube_batch_tpu/actions/alpha.py", ACTION_FIXTURE.format(cls="Alpha", name="alpha")),
+        sf("kube_batch_tpu/actions/beta.py", ACTION_FIXTURE.format(cls="Beta", name="beta")),
+        sf("kube_batch_tpu/scheduler.py", LOOP_FIXTURE),
+    ]
+    findings = registry_consistency.analyze(files, repo=str(tmp_path))
+    got = sorted((f.code, f.symbol, f.path) for f in findings)
+    assert got == [
+        # registered, but the span every cycle running it opens is undeclared
+        ("KBT-R007", "span:action.beta", "kube_batch_tpu/actions/beta.py"),
+        ("KBT-R007", "span:stage.*", "kube_batch_tpu/scheduler.py"),
+        # declared, but names no registered action
+        ("KBT-R008", "span:action.ghost", "kube_batch_tpu/obs/__init__.py"),
+    ]
+
+
 ENV_READER_FIXTURE = (
     "import os\n"
     'A = os.environ.get("KBT_ALPHA", "")\n'

@@ -134,7 +134,8 @@ def test_off_every_entry_point_is_the_noop_singleton():
     assert not obs.enabled()
     assert obs.span("cycle") is obs.NOOP_SPAN
     assert obs.span("cycle", parent=("abc", "def"), attr=1) is obs.NOOP_SPAN
-    assert obs.annotate("kbt.solve") is obs.NOOP_SPAN
+    # the solver's span is its own profiler annotation: off, it is the no-op
+    assert obs.span("solve") is obs.NOOP_SPAN
     assert obs.current() is None
     assert obs.current_headers() == {}
     assert obs.from_headers({obs.HDR_TRACE: "t", obs.HDR_SPAN: "s"}) is None
@@ -168,7 +169,48 @@ def test_off_overhead_is_one_branch(tmp_path):
     assert off_cost < 5e-5
 
 
+def test_off_cycle_never_touches_the_profiler(tmp_path, monkeypatch):
+    """With tracing off a whole cycle — every new span site included —
+    takes the no-op path: the profiler annotation is never resolved."""
+    assert not obs.enabled()
+
+    def resolved():
+        raise AssertionError("the off path reached the profiler")
+
+    monkeypatch.setattr(obs, "_annotation_cls", resolved)
+    store = ClusterStore()
+    seed_store(store)
+    _, sched = make_scheduler(store, tmp_path)
+    sched.run_once()
+    assert obs.recorder.spans() == []
+    assert all(p.node_name for p in store.list(PODS))
+
+
+def test_off_ingest_counting_is_one_increment():
+    """The per-event ingest path with tracing off: one tally increment
+    and one branch around the handler — a generous per-event bound so a
+    timer pair or a metrics lock on the off path fails loudly."""
+    cache = SchedulerCache(ClusterStore())
+    calls = []
+    counted = cache._counted("pod", "add", calls.append)
+    n = 20_000
+    t0 = time.perf_counter()
+    for i in range(n):
+        counted(i)
+    per_event = (time.perf_counter() - t0) / n
+    assert len(calls) == n and cache._ingest[("pod", "add")][:2] == [n, 0.0]
+    assert per_event < 2e-5
+
+
 # -- span trees --------------------------------------------------------------
+
+
+def _ancestors(span, by_id):
+    out = []
+    while span["parent_id"] in by_id:
+        span = by_id[span["parent_id"]]
+        out.append(span["name"])
+    return out
 
 
 def test_full_cycle_span_tree(tmp_path, tracing):
@@ -184,9 +226,12 @@ def test_full_cycle_span_tree(tmp_path, tracing):
     spans = obs.recorder.spans()
     assert obs.check_tree(spans) == []
     by = spans_by_name(spans)
-    for name in ("cycle", "snapshot", "encode", "solve", "gang.assign",
-                 "dispatch", "journal.append", "commit"):
+    for name in ("cycle", "session.open", "snapshot", "snapshot.nodes",
+                 "snapshot.jobs", "action.enqueue", "action.xla_allocate",
+                 "encode", "solve", "replay", "dispatch", "journal.append",
+                 "session.close", "commit"):
         assert name in by, f"missing {name} span; got {sorted(by)}"
+    assert "gang.assign" not in by
     cycles = [s for s in by["cycle"] if s["attrs"].get("cycle") == 1]
     assert len(cycles) == 1
     root = cycles[0]
@@ -200,6 +245,105 @@ def test_full_cycle_span_tree(tmp_path, tracing):
     # the gang.bind spans crossed the kb-write pool but kept the trace
     assert any(s["name"] == "gang.bind" and s["trace_id"] == root["trace_id"]
                for s in spans) or "gang.bind" not in by
+
+    # the nesting: the cycle is session open + one span per action +
+    # session close; dispatch is replay's sibling, not its child
+    by_id = {s["span_id"]: s for s in cycle_spans}
+    one = {s["name"]: s for s in cycle_spans}
+    parent = {name: by_id[s["parent_id"]]["name"] for name, s in one.items()
+              if s["parent_id"] in by_id}
+    assert parent["session.open"] == parent["session.close"] == "cycle"
+    assert parent["action.enqueue"] == parent["action.xla_allocate"] == "cycle"
+    assert parent["snapshot"] == "session.open"
+    assert parent["snapshot.nodes"] == parent["snapshot.jobs"] == "snapshot"
+    assert parent["commit"] == "session.close"
+    for name in ("encode", "solve", "replay", "dispatch"):
+        assert parent[name] == "action.xla_allocate", name
+    assert "replay" not in _ancestors(one["dispatch"], by_id)
+    assert one["snapshot.nodes"]["attrs"] == {"objects": 4, "tasks": 0}
+    assert one["snapshot.jobs"]["attrs"] == {"objects": 2, "tasks": 8}
+    assert one["replay"]["attrs"] == {"gangs": 2, "tasks": 8}
+    covered = sum(s["dur_us"] for s in cycle_spans
+                  if s["parent_id"] == root["span_id"])
+    assert covered <= root["dur_us"] + len(cycle_spans)
+
+
+def test_spans_nest_on_the_profiler_clock(tmp_path, tracing):
+    """Every span is a ``kbt.<name>`` profiler annotation: one cycle
+    under the JAX profiler nests on the host plane as the span tree
+    does."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    store = ClusterStore()
+    seed_store(store)
+    _, sched = make_scheduler(store, tmp_path)
+    trace_dir = tmp_path / "profile"
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        sched.run_once()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(trace_dir / "**" / "*.xplane.pb"), recursive=True)
+    found: dict[str, list] = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("kbt."):
+                    found.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+
+    def inside(inner, outer):
+        (i0, i1), (o0, o1) = found[inner][0], found[outer][0]
+        return o0 <= i0 and i1 <= o1
+
+    assert inside("kbt.session.open", "kbt.cycle")
+    assert inside("kbt.snapshot", "kbt.session.open")
+    assert inside("kbt.snapshot.jobs", "kbt.snapshot")
+    assert inside("kbt.replay", "kbt.action.xla_allocate")
+    assert inside("kbt.solve", "kbt.action.xla_allocate")
+    assert "kbt.time_to_bind" not in found  # emitted spans carry no annotation
+
+
+def test_cache_ingest_counts_events_and_times_them_only_when_tracing(
+        monkeypatch):
+    obs.configure("off")
+    store = ClusterStore()
+    cache = SchedulerCache(store)
+    events, seconds = metrics.cache_events, metrics.cache_event_seconds
+
+    def read(kind, verb):
+        labels = {"kind": kind, "verb": verb}
+        return events.value(labels), seconds.value(labels)
+
+    before = {k: read(*k) for k in (("pod", "add"), ("node", "add"),
+                                    ("podgroup", "add"), ("queue", "add"))}
+    seed_store(store, gangs=1, members=3, nodes=2)
+    # tallied per cache; exported only when a snapshot folds them in
+    assert read("pod", "add") == before[("pod", "add")]
+    cache.snapshot()
+    for (kind, verb), n in {("pod", "add"): 3, ("node", "add"): 2,
+                            ("podgroup", "add"): 1, ("queue", "add"): 1}.items():
+        assert read(kind, verb) == (before[(kind, verb)][0] + n,
+                                    before[(kind, verb)][1]), kind
+    monkeypatch.setenv(obs.ENV, "1")
+    obs.configure()
+    try:
+        store.create_pod(build_pod(name="g0-p3", group_name="g0",
+                                   req=build_resource_list(cpu=1, memory="512Mi")))
+        cache.snapshot()
+    finally:
+        obs.configure("off")
+        obs.recorder.clear()
+    n, secs = read("pod", "add")
+    assert n == before[("pod", "add")][0] + 4
+    assert secs > before[("pod", "add")][1]
+    cache.snapshot()  # nothing new: a second fold adds nothing
+    assert read("pod", "add") == (n, secs)
 
 
 def test_journal_records_carry_the_cycle_trace(tmp_path, tracing):
