@@ -101,7 +101,9 @@ class TaskInfo:
         are never mutated on a TaskInfo after construction (no call site
         does — the accounting arithmetic mutates node/job aggregates only),
         so sharing them is exact and saves two Resource copies per
-        assignment on the bulk replay path."""
+        assignment on the bulk replay path. The session snapshot relies on
+        the same invariant: ``NodeInfo.clone`` and ``JobInfo.clone`` copy
+        every resident task this way, every cycle."""
         ti = TaskInfo.__new__(TaskInfo)
         ti.uid = self.uid
         ti.job = self.job
@@ -257,8 +259,10 @@ class JobInfo:
         info.creation_timestamp = self.creation_timestamp
         info.pod_group = self.pod_group
         info.pdb = self.pdb
+        # Residency clones: each copy has its own status but shares the
+        # source's resource vectors (see TaskInfo.clone_for_residency).
         for task in self.tasks.values():
-            info.add_task_info(task.clone())
+            info.add_task_info(task.clone_for_residency())
         return info
 
     # -- gang predicates ----------------------------------------------------

@@ -39,10 +39,38 @@ class NodeInfo:
         Resident tasks are committed facts; replay them with overcommit
         tolerance so cloning (the per-cycle snapshot) of a node two
         shards raced binds onto reproduces the negative idle instead of
-        aborting the whole scheduling cycle."""
+        aborting the whole scheduling cycle.
+
+        The replay is ``add_task(task, overcommit=True)``'s accounting, in
+        the task map's order, so the aggregates come out bit-identical; it
+        skips what a copy cannot need: re-deriving each key, the duplicate
+        check, and the two Resource copies of ``TaskInfo.clone``. Each
+        copy is a ``clone_for_residency`` and shares its source's resource
+        vectors, which relies on no code mutating a task's ``resreq`` or
+        ``init_resreq`` in place."""
         res = NodeInfo(self.node)
-        for task in self.tasks.values():
-            res.add_task(task, overcommit=True)
+        tasks = res.tasks
+        if self.node is None:
+            for key, task in self.tasks.items():
+                tasks[key] = task.clone_for_residency()
+        else:
+            releasing, idle, used = res.releasing, res.idle, res.used
+            add_releasing, sub_releasing = releasing.add, releasing.sub_overcommit
+            sub_idle, add_used = idle.sub_overcommit, used.add
+            RELEASING, PIPELINED = TaskStatus.RELEASING, TaskStatus.PIPELINED
+            for key, task in self.tasks.items():
+                ti = task.clone_for_residency()
+                req = ti.resreq
+                status = ti.status
+                if status == RELEASING:
+                    add_releasing(req)
+                    sub_idle(req)
+                elif status == PIPELINED:
+                    sub_releasing(req)
+                else:
+                    sub_idle(req)
+                add_used(req)
+                tasks[key] = ti
         res.other = self.other
         return res
 

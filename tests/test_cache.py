@@ -4,6 +4,7 @@ write side, resync, GC, and snapshot policy)."""
 
 from __future__ import annotations
 
+import pathlib
 import time
 
 import pytest
@@ -18,7 +19,9 @@ from kube_batch_tpu.apis.types import (
     PriorityClass,
 )
 from kube_batch_tpu.cache import ClusterStore, SchedulerCache, shadow_pod_group
+from kube_batch_tpu.scheduler import Scheduler
 from kube_batch_tpu.testing import (
+    FakeEvictor,
     build_node,
     build_pod,
     build_pod_group,
@@ -431,3 +434,67 @@ def test_annotated_pod_survives_group_annotation(store, cache):
     store.create_pod_group(build_pod_group("pg1"))
     store.create_pod(pod)
     assert len(cache.jobs["default/pg1"].tasks) == 1
+
+
+EXAMPLES = pathlib.Path(__file__).resolve().parent.parent / "examples"
+
+
+def _vector_bits(r):
+    return (r.milli_cpu, r.memory, dict(r.scalars), r.max_task_num)
+
+
+@pytest.mark.parametrize("conf", ["scheduler-conf.yaml", "scheduler-conf-tpu.yaml"])
+def test_scheduling_cycle_never_mutates_task_vectors(store, conf):
+    """Snapshot clones share each cached task's ``resreq``/``init_resreq``
+    with the cache's own TaskInfo, which is exact only while no code
+    mutates a task's resource vectors in place. One full cycle that
+    enqueues, reclaims, allocates, backfills and preempts must leave every
+    cached task's vectors reading what they read before it."""
+    evictor = FakeEvictor()
+    sc = SchedulerCache(store, evictor=evictor)
+    try:
+        store.create_queue(build_queue("a"))
+        store.create_queue(build_queue("b"))
+        for i in range(4):
+            store.create_node(build_node(f"n{i}", build_resource_list(cpu=2, memory="4Gi", pods=10)))
+        # queue a fills the cluster with low-priority residents
+        store.create_pod_group(build_pod_group("low", queue="a", min_member=1))
+        for i in range(8):
+            store.create_pod(build_pod(
+                name=f"low-{i}", group_name="low", node_name=f"n{i // 2}",
+                phase=PodPhase.RUNNING, priority=1,
+                req=build_resource_list(cpu=1, memory="1Gi", **{"nvidia.com/gpu": 0}),
+            ))
+        # a high-priority gang in queue a preempts; queue b's job reclaims
+        store.create_pod_group(build_pod_group("high", queue="a", min_member=2))
+        for i in range(2):
+            store.create_pod(build_pod(name=f"high-{i}", group_name="high", priority=9,
+                                       req=build_resource_list(cpu=1, memory="1Gi")))
+        store.create_pod_group(build_pod_group("other", queue="b", min_member=1))
+        store.create_pod(build_pod(name="other-0", group_name="other",
+                                   req=build_resource_list(cpu="1001m", memory="1Gi")))
+
+        def cached_tasks():
+            with sc._mutex:
+                return [t for j in sc.jobs.values() for t in j.tasks.values()] + [
+                    t for n in sc.nodes.values() for t in n.tasks.values()
+                ]
+
+        def vectors(t):
+            return _vector_bits(t.resreq), _vector_bits(t.init_resreq)
+
+        tasks = cached_tasks()
+        before = [vectors(t) for t in tasks]
+        by_uid = {t.uid: vectors(t) for t in tasks}
+        assert len(by_uid) == 11
+
+        Scheduler(sc, scheduler_conf=str(EXAMPLES / conf)).run_once()
+        wait_until(lambda: len(evictor.evicts) > 0, what="an eviction")
+
+        assert [vectors(t) for t in tasks] == before
+        after = cached_tasks()
+        assert {t.uid for t in after} == set(by_uid)
+        for t in after:
+            assert vectors(t) == by_uid[t.uid], t.uid
+    finally:
+        sc.stop()

@@ -135,3 +135,103 @@ class TestJobInfo:
         # mutating the clone must not affect the original
         c.update_task_status(next(iter(c.tasks.values())), TaskStatus.ALLOCATED)
         assert job.ready_task_num() == 0
+
+
+# -- the snapshot clone against the add_task_info replay it stands in for ------
+
+GPU = "nvidia.com/gpu"
+
+
+def _task(name, cpu, mem, status, **scalars):
+    t = build_task(name=name, req=build_resource_list(cpu, mem, **scalars), group_name="j1")
+    t.status = status
+    return t
+
+
+def _running_only():
+    job = JobInfo("default/j1")
+    for i, (cpu, mem) in enumerate([("100m", "128Mi"), ("250m", "256Mi"), ("500m", "512Mi")] * 3):
+        job.add_task_info(_task(f"r{i}", cpu, mem, TaskStatus.RUNNING))
+    return job
+
+
+def _every_status():
+    job = JobInfo("default/j1")
+    for i, status in enumerate([TaskStatus.PENDING, TaskStatus.RUNNING, TaskStatus.RELEASING,
+                                TaskStatus.PIPELINED, TaskStatus.ALLOCATED, TaskStatus.BOUND,
+                                TaskStatus.SUCCEEDED, TaskStatus.PENDING, TaskStatus.RUNNING]):
+        job.add_task_info(_task(f"s{i}", "500m", "512Mi", status))
+    return job
+
+
+def _fractional_history():
+    job = JobInfo("default/j1")
+    tasks = [
+        _task(f"f{i}", cpu, "100Mi", status, **{GPU: gpu})
+        for i, (cpu, gpu, status) in enumerate([
+            (1.001, 0.3, TaskStatus.RUNNING), (0.333, 0.7, TaskStatus.PENDING),
+            (2.017, 0.1, TaskStatus.RUNNING), (1.001, 0.3, TaskStatus.ALLOCATED),
+            (0.129, 0.9, TaskStatus.RELEASING), (3.003, 0.3, TaskStatus.PENDING),
+        ])
+    ]
+    for t in tasks:
+        job.add_task_info(t)
+    for t in tasks[1::2]:
+        job.delete_task_info(t)
+    job.update_task_status(tasks[0], TaskStatus.RELEASING)
+    job.add_task_info(_task("late", 0.777, "1Gi", TaskStatus.BOUND, **{GPU: 0.3}))
+    job.add_task_info(tasks[3])
+    return job
+
+
+JOB_MIXES = {
+    "running_only": _running_only,
+    "every_status": _every_status,
+    "fractional_history": _fractional_history,
+}
+
+
+def _replayed_clone(job):
+    """The snapshot clone as an add_task_info replay of fresh task copies."""
+    info = JobInfo(job.uid)
+    for task in job.tasks.values():
+        info.add_task_info(task.clone())
+    return info
+
+
+def _bits(r):
+    return (r.milli_cpu, r.memory, list(r.scalars.items()), r.max_task_num)
+
+
+class TestSnapshotClone:
+    @pytest.mark.parametrize("mix", sorted(JOB_MIXES))
+    def test_clone_matches_add_task_info_replay_bit_for_bit(self, mix):
+        job = JOB_MIXES[mix]()
+        got, want = job.clone(), _replayed_clone(job)
+        assert _bits(got.total_request) == _bits(want.total_request)
+        assert _bits(got.allocated) == _bits(want.allocated)
+        assert list(got.tasks) == list(want.tasks) == list(job.tasks)
+        assert [(s, list(ts)) for s, ts in got.task_status_index.items()] == [
+            (s, list(ts)) for s, ts in want.task_status_index.items()
+        ]
+        for uid, ti in got.tasks.items():
+            exp = want.tasks[uid]
+            assert (ti.status, ti.node_name, ti.pod) == (exp.status, exp.node_name, exp.pod)
+            assert _bits(ti.resreq) == _bits(exp.resreq)
+            assert got.task_status_index[ti.status][uid] is ti
+
+    @pytest.mark.parametrize("mix", sorted(JOB_MIXES))
+    def test_clone_shares_vectors_but_not_status(self, mix):
+        job = JOB_MIXES[mix]()
+        before = (_bits(job.total_request), _bits(job.allocated))
+        c = job.clone()
+        for uid, ti in c.tasks.items():
+            src = job.tasks[uid]
+            assert ti is not src
+            assert ti.resreq is src.resreq and ti.init_resreq is src.init_resreq
+        uid, ti = next((u, t) for u, t in c.tasks.items() if t.status == TaskStatus.RUNNING)
+        c.update_task_status(ti, TaskStatus.RELEASING)
+        assert job.tasks[uid].status == TaskStatus.RUNNING
+        assert uid in job.task_status_index[TaskStatus.RUNNING]
+        assert uid not in job.task_status_index.get(TaskStatus.RELEASING, {})
+        assert (_bits(job.total_request), _bits(job.allocated)) == before
