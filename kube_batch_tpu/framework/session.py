@@ -619,3 +619,20 @@ def _close_session(ssn: Session, discard: bool) -> None:
     ssn.queues = {}
     ssn.plugins = {}
     ssn.event_handlers = []
+    # The plugins' callbacks close over the session and its world (e.g.
+    # tensorscore's node list): dropped with it, the clones die here by
+    # refcount instead of waiting, as one reference cycle, for the
+    # cyclic collector.
+    ssn.job_order_fns = {}
+    ssn.queue_order_fns = {}
+    ssn.task_order_fns = {}
+    ssn.predicate_fns = {}
+    ssn.node_order_fns = {}
+    ssn.node_map_fns = {}
+    ssn.node_reduce_fns = {}
+    ssn.preemptable_fns = {}
+    ssn.reclaimable_fns = {}
+    ssn.overused_fns = {}
+    ssn.job_ready_fns = {}
+    ssn.job_pipelined_fns = {}
+    ssn.job_valid_fns = {}

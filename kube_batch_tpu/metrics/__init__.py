@@ -336,6 +336,18 @@ cache_event_seconds = Counter(
     "(timed only while tracing is on; folded in once per snapshot)",
 )
 
+# -- cyclic garbage collector (kube_batch_tpu.utils.collector) --------------
+gc_collections = Counter(
+    f"{_SUBSYSTEM}_gc_collections_total",
+    "Cyclic garbage collections, by when: started on their own inside a "
+    "scheduling cycle (cycle) or outside one (between), or run at the cycle "
+    "boundary over the unfrozen heap (boundary) or the whole heap (full)",
+)
+gc_pause_seconds = Counter(
+    f"{_SUBSYSTEM}_gc_pause_seconds_total",
+    "Seconds spent in cyclic garbage collections, by when (as gc_collections_total)",
+)
+
 # -- incremental encode cache (kube_batch_tpu.ops.encode_cache) --------------
 encode_cache_hits = Counter(
     f"{_SUBSYSTEM}_encode_cache_hits_total",
@@ -712,6 +724,11 @@ def register_watch_relist(kind: str) -> None:
     watch_relists.inc({"kind": kind})
 
 
+def register_gc_pause(when: str, seconds: float, n: int = 1) -> None:
+    gc_collections.inc({"when": when}, by=n)
+    gc_pause_seconds.inc({"when": when}, by=seconds)
+
+
 def register_encode_cache_hits(n: int) -> None:
     encode_cache_hits.inc(by=n)
 
@@ -1006,6 +1023,8 @@ def render_prometheus_text() -> str:
         watch_relists,
         cache_events,
         cache_event_seconds,
+        gc_collections,
+        gc_pause_seconds,
         encode_cache_hits,
         encode_cache_invalidations,
         encode_warm_fraction,

@@ -137,6 +137,8 @@ SPAN_NAMES = (
     "store.txn",      # store-arbiter side of a coalesced txn batch (remote)
     "time_to_bind",   # synthetic: streaming arrival -> bind echo, per pod
     "explain",        # post-solve unschedulability forensics (obs/explain)
+    "gc",             # cycle boundary: cyclic collection + freeze (utils/collector)
+    "gc.pause",       # synthetic: a collection the interpreter started on its own
 )
 
 # Every /debug/* route server.py serves. Checked both directions by the

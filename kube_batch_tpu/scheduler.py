@@ -41,6 +41,7 @@ from kube_batch_tpu.conf import (
 from kube_batch_tpu.faults import mutation_detector
 from kube_batch_tpu.framework import close_session, open_session
 from kube_batch_tpu.recovery.budget import CycleBudget, CycleDeadlineExceeded
+from kube_batch_tpu.utils import collector
 
 
 def _env_float(name: str, default: float) -> float:
@@ -301,7 +302,7 @@ class Scheduler:
                 work.stale_reason,
             )
             return False
-        with obs.span("micro_cycle", gangs=len(work.gangs)) as mspan:
+        with obs.span("micro_cycle", gangs=len(work.gangs)) as mspan, collector.cycle():
             if faults.should_fire("stream.micro_cycle"):
                 # injected micro-solve failure: invalidate and degrade to the
                 # backstop full cycle — the backlog is untouched, no pod drops
@@ -412,7 +413,10 @@ class Scheduler:
         cycle_start = time.perf_counter()
         self._load_conf()  # before the span: a conf push may flip tracing
 
-        with obs.span("cycle") as cspan:
+        # collector.cycle(): no automatic cyclic collection inside the
+        # cycle; its boundary (utils/collector.py) collects and freezes
+        # the survivors on the way out, still inside the cycle span
+        with obs.span("cycle") as cspan, collector.cycle():
             # Dispatch fence (pipeline.py, KBT_PIPELINE): the previous
             # cycle's deferred dispatch must land before this cycle
             # snapshots — same ordering the synchronous path gets for
