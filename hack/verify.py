@@ -79,14 +79,6 @@ Runs, in order:
     pod-for-pod identically across two cycles, with in-solve splits
     and second-cycle re-merges both exercised.
 
-With ``--bench-diff OLD NEW``, two bench artifacts (fresh bench.py
-output or archived BENCH_*.json wrappers) are regression-gated via
-hack/bench_diff.py --strict: >15% p50 regressions, parity flips,
-compile-budget changes and vanished rows all fail the gate. With
-``--bench-diff`` and no paths, the two newest ``BENCH_*.json`` in the
-repo root are auto-discovered (mtime order, name as tie-break) and
-diffed oldest-of-the-pair -> newest.
-
 With ``--chaos``, two more gates run: the chaos-marked pytest subset
 (tests/test_faults.py + tests/test_recovery.py + tests/test_federation.py
 — fault drills, the crash-consistent failover e2e, the conflict chaos
@@ -118,10 +110,9 @@ pumps and listeners shut down clean).
 Exit 0 iff every gate is clean.
 Usage:  python hack/verify.py [--strict] [--chaos] [--federation]
                               [--obs] [--interleave] [--json]
-                              [--bench-diff [OLD.json NEW.json]]
 
 ``--json`` appends one machine-readable summary line to stdout
-(per-gate pass/fail + finding counts) so bench/CI can record the
+(per-gate pass/fail + finding counts) so CI can record the
 gate's state in artifacts.
 
 CI/the deployment image run ``--strict`` (the Dockerfile installs ruff +
@@ -141,7 +132,7 @@ import tabnanny
 import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-TARGETS = ["kube_batch_tpu", "tests", "bench.py", "__graft_entry__.py", "chip_smoke.py", "hack"]
+TARGETS = ["kube_batch_tpu", "tests", "__graft_entry__.py", "chip_smoke.py", "hack"]
 
 # Names a module may import without using (re-export / side-effect
 # registration idioms used deliberately in this codebase).
@@ -636,32 +627,6 @@ def run_fleet_gate(env: dict) -> dict:
     return out
 
 
-def run_bench_diff_gate(old: str, new: str) -> dict:
-    """--bench-diff OLD NEW: hack/bench_diff.py in --strict mode — a
-    >15% p50 regression, a parity flip, a compile-budget change or a
-    vanished row in NEW vs OLD fails the gate."""
-    import json
-
-    res = subprocess.run(
-        [sys.executable, os.path.join(REPO, "hack", "bench_diff.py"),
-         old, new, "--json", "--strict"],
-        cwd=REPO, capture_output=True, text=True,
-    )
-    summary: dict = {}
-    try:
-        summary = json.loads(res.stdout.strip().splitlines()[-1])
-    except (ValueError, IndexError):
-        print("verify: bench_diff produced no parseable summary")
-    if res.returncode != 0 or not summary.get("ok", False):
-        print(res.stdout.rstrip())
-        print(f"verify: bench diff FAILED ({old} -> {new})")
-    return {
-        "ok": res.returncode == 0 and summary.get("ok", False),
-        "findings": len(summary.get("findings", [])),
-        "rows": summary.get("rows_new"),
-    }
-
-
 def run_analysis_gate(strict: bool) -> dict:
     """The domain-aware suite as a subprocess (same pattern as the fsck
     gate: the CLI is the contract). Returns a summary dict for --json."""
@@ -843,32 +808,6 @@ def main(argv: list[str] | None = None) -> int:
     as_json = "--json" in argv
     interleave = "--interleave" in argv
     federation = "--federation" in argv
-    bench_diff: tuple[str, str] | None = None
-    if "--bench-diff" in argv:
-        i = argv.index("--bench-diff")
-        paths = [a for a in argv[i + 1:i + 3] if not a.startswith("--")]
-        if len(paths) == 1:
-            print("verify: --bench-diff takes two bench JSON paths (OLD NEW) "
-                  "or none, to auto-discover the two newest BENCH_*.json")
-            return 2
-        if not paths:
-            import glob
-
-            found = sorted(
-                glob.glob(os.path.join(REPO, "BENCH_*.json")),
-                key=lambda p: (os.path.getmtime(p), p),
-            )
-            if len(found) < 2:
-                print("verify: --bench-diff auto-discovery needs at least "
-                      "two BENCH_*.json artifacts in the repo root")
-                return 2
-            bench_diff = (found[-2], found[-1])
-            print("verify: bench-diff auto-discovered "
-                  f"{os.path.basename(found[-2])} -> "
-                  f"{os.path.basename(found[-1])}")
-        else:
-            bench_diff = (paths[0], paths[1])
-        argv = argv[:i] + argv[i + 1 + len(paths):]
     unknown = [
         a for a in argv
         if a not in ("--strict", "--chaos", "--json", "--interleave",
@@ -1211,14 +1150,6 @@ def main(argv: list[str] | None = None) -> int:
         }
         if not storm_ok:
             print(f"verify: admission storm drill FAILED ({storm})")
-            failed = True
-
-    # 9. --bench-diff OLD NEW: regression-gate two bench artifacts
-    # (hack/bench_diff.py --strict — p50 regressions, parity flips,
-    # compile-budget changes, vanished rows)
-    if bench_diff is not None:
-        gates["bench_diff"] = run_bench_diff_gate(*bench_diff)
-        if not gates["bench_diff"]["ok"]:
             failed = True
 
     print("verify:", "FAILED" if failed else "ok",

@@ -119,14 +119,16 @@ class XlaAllocateAction(Action):
         from kube_batch_tpu.ops.encode_cache import TensorArena
 
         self._arena = TensorArena()
-        # Wall-clock split of the last execute() (bench.py reads this).
+        # Wall-clock split of the last execute() (benchmark/run.py and
+        # chip_smoke.py read this).
         self.last_timings: dict[str, float] = {}
         # Devices in the mesh the last execute() resolved (1 = single-chip);
         # the driver dryrun asserts on this to prove the sharded path ran.
         self.last_mesh_size = 1
         # Which rung actually solved the last execute() ("mesh_pallas",
-        # "sharded_xla", "pallas", "xla", "serial"); bench rows assert on
-        # this so a silent downgrade cannot masquerade as evidence.
+        # "sharded_xla", "pallas", "xla", "serial"); Scheduler and the
+        # benchmark read this so a silent downgrade cannot masquerade as
+        # evidence.
         self.last_solver_tier = "none"
         # Block backend of the last mesh_pallas solve ("mosaic",
         # "interpret", "jnp"; "" off that rung): chip_smoke asserts the
@@ -134,13 +136,13 @@ class XlaAllocateAction(Action):
         self.last_block_impl = ""
         # Gang iterations the last execute() committed from K-deep
         # batched mesh exchanges (KBT_EXCHANGE_BATCH; 0 off the batched
-        # program). Bench rows read this as amortization evidence.
+        # program). Tests read this as amortization evidence.
         self.last_batched_iters = 0
         # Stats dict from the last class-compressed solve (ops/class_solve,
         # KBT_CLASS_COMPRESS): class_count, compression_ratio, splits,
         # remerges, group_s/kernel_s solve-cost split. None when the
-        # compression was off or degraded for the cycle; bench rows read
-        # this as the compression-honesty evidence.
+        # compression was off or degraded for the cycle; tests read this
+        # as the compression-honesty evidence.
         self.last_class_stats = None
         # Whether the last FULL-cycle encode saw any pod-affinity terms
         # (pending or resident). Streaming micro-cycles pass this as the

@@ -766,8 +766,8 @@ def storm(
     and optionally a SIGKILL'd shard mid-storm (leased slots + survivor
     adoption + MTTR, exactly-once, fsck-clean, zero journal orphans).
 
-    Invariant-gated: throughput/latency numbers are measured output for
-    the bench row; ``ok`` only checks correctness invariants plus the
+    Invariant-gated: throughput/latency numbers are reported as
+    measured; ``ok`` only checks correctness invariants plus the
     protected lane's p99 bound when admission is ON.
     """
     import json as _json
@@ -1161,7 +1161,7 @@ def storm(
 
 
 def storm_row(shards: int = 2, duration_s: float = 8.0) -> dict:
-    """The headline bench row: the same storm with admission ON,
+    """The storm drill's three cells: the same storm with admission ON,
     admission OFF (measured collapse), and ON + SIGKILL'd shard
     (adoption + MTTR)."""
     on = storm(shards=shards, duration_s=duration_s, admission_on=True)

@@ -44,7 +44,7 @@ AST suite (own CLI: ``python -m kube_batch_tpu.analysis.trace``, own
 baseline ``hack/trace-baseline.toml`` so the two gates never mark each
 other's suppressions stale). The runtime sibling
 (:mod:`kube_batch_tpu.analysis.trace.sentinel`) pins compile budgets
-on the same entry points in tier-1 and bench.
+on the same entry points in tier-1.
 
 jax is imported lazily inside functions — importing this module (e.g.
 for the CLI's ``--explain``) stays cheap and device-free.
@@ -85,6 +85,7 @@ _CALLBACK_PRIMS = frozenset(
         "pure_callback",
         "io_callback",
         "debug_callback",
+        "debug_print",
         "callback",
         "outside_call",  # legacy host_callback
         "host_callback_call",
@@ -231,7 +232,9 @@ def check_large_consts(
     threshold."""
     findings = []
     for const in _all_consts(closed):
-        nbytes = getattr(const, "nbytes", 0)
+        # sized from the array itself: jax's constant wrapper
+        # (TypedNdArray) carries no ``nbytes`` of its own
+        nbytes = np.asarray(const).nbytes
         if nbytes > const_bytes:
             shape = tuple(getattr(const, "shape", ()))
             dtype = getattr(const, "dtype", "?")

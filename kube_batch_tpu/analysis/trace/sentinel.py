@@ -14,10 +14,10 @@ compile; :class:`CompileSentinel` turns that into an assertable budget:
             solver.solve(arrays)
     # raises CompileBudgetExceeded if anything recompiled
 
-Used three ways (ISSUE 7): tier-1 pins zero recompiles across 3 warm
-cycles of the XLA twin and the mesh rungs; ``bench.py`` asserts per-row
-budgets (the measured repeats of a warmed row must not compile); and
-the seeded recompile-storm fixture in the tests proves the counter
+Used three ways: tier-1 pins zero recompiles across 3 warm cycles of
+the XLA twin and the mesh rungs; ``benchmark/run.py`` reads
+:func:`compile_count` to report compiles inside its measured window;
+and the seeded recompile-storm fixture in the tests proves the counter
 actually sees shape-keyed jit churn.
 
 The listener is global and lazily registered (jax keeps listeners for

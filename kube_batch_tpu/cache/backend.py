@@ -172,7 +172,7 @@ class StoreBackend:
 class InProcessBackend(ClusterStore):
     """The in-process store IS the backend — the single-process fast
     path. A distinct class (rather than an alias) so deployments can
-    name which backend they constructed in logs and bench rows."""
+    name which backend they constructed in logs."""
 
 
 class LoopbackBackend:
@@ -218,9 +218,10 @@ class LoopbackBackend:
             _pool_size(),
             timeout,
         )
-        # Cumulative protocol bytes (tx/rx) for bench rows; the metric
-        # family store_backend_bytes_total is process-global, these are
-        # per-backend so a bench can report wire_bytes_per_bind per row.
+        # Cumulative protocol bytes (tx/rx); the metric family
+        # store_backend_bytes_total is process-global, these are
+        # per-backend so the federation smoke can report
+        # wire_bytes_per_bind per run.
         self.bytes_tx = 0  #: guarded_by _lock
         self.bytes_rx = 0  #: guarded_by _lock
         self._lock = threading.RLock()

@@ -216,7 +216,7 @@ class ClusterStore:
 
     def node_allocated(self, node: str) -> Any:
         """Clone of the incremental allocated-resource sum for ``node``
-        (bound, non-terminal pods). Bench/fsck introspection."""
+        (bound, non-terminal pods). Fsck introspection."""
         from kube_batch_tpu.api.resource_info import Resource
 
         with self._lock:

@@ -30,7 +30,7 @@ pods share a podgroup, hence a queue and a namespace).
 
 Deployment shapes:
 
-- in-process (bench, interleave explorer): N ``FederatedCache`` over
+- in-process (tests, interleave explorer): N ``FederatedCache`` over
   one ``InProcessBackend``;
 - networked (docker-compose topology in deployment/): N scheduler
   processes, each a ``LoopbackBackend`` speaking ``/backend/v1/`` to
@@ -1034,7 +1034,8 @@ def smoke(
        a single-scheduler twin on an identical world (which pods bind
        is deterministic; which node wins a race is not).
 
-    ``protocol``/``codec`` pin the wire generation for bench rows:
+    ``protocol``/``codec`` pin the wire generation (the CLI's
+    ``--wire-protocol``/``--codec``):
     ``protocol=1`` runs the whole topology on the pre-v2 surface
     (server pinned, clients capped), ``protocol=2`` the full v2 stack.
     When pinned, the result additionally carries the measured row —

@@ -605,7 +605,7 @@ arena_hbm_bytes = Gauge(
 arena_hbm_watermark = Gauge(
     f"{_SUBSYSTEM}_arena_hbm_watermark_bytes",
     "High watermark of total device bytes held by the tensor arena "
-    "since process start (the bench's HBM column)",
+    "since process start",
 )
 
 # -- node-class compressed solve (ops/class_solve, KBT_CLASS_COMPRESS) -------

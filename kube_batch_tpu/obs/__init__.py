@@ -613,8 +613,8 @@ class QuantileSketch:
         return sum(sl[3] for sl in self._slices.values())
 
     def quantile(self, q: float) -> float:
-        """Nearest-rank quantile (target rank ``ceil(q*n)``, the same
-        rule the repo's bench percentile uses) within relative error
+        """Nearest-rank quantile (target rank ``ceil(q*n)``) within
+        relative error
         ``alpha``; 0.0 for an empty sketch."""
         n = self.count()
         if n == 0:

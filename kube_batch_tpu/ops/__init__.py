@@ -26,7 +26,7 @@ _CHECKOUT_CACHE = _os.path.join(
 def enable_compilation_cache() -> str | None:
     """Persistent XLA compilation cache: the solve recompiles only when a
     padding bucket changes shape, but a fresh process (server restart,
-    bench run, failover standby taking over) pays each bucket's compile
+    chip smoke, failover standby taking over) pays each bucket's compile
     again without one. Returns the directory in use, or None when off.
 
     - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and this
@@ -34,8 +34,8 @@ def enable_compilation_cache() -> str | None:
     - otherwise ``<checkout>/.jax_cache/``;
     - ``KBT_JAX_CACHE=0`` turns the cache off.
 
-    Called by the scheduler entry points (Scheduler init, bench, the
-    graft entry) — deliberately NOT at import, so an embedding
+    Called by the scheduler entry points (Scheduler init, chip_smoke.py,
+    the graft entry) — deliberately NOT at import, so an embedding
     application that configures jax itself keeps full control no matter
     the import order; it defers to any cache dir already set."""
     env_dir = _os.environ.get("JAX_COMPILATION_CACHE_DIR")

@@ -646,7 +646,7 @@ class ClassCompressedSolver:
             # node-sharded structures stay host-side (per-shard member
             # lists drive the expansion below)
             self._sharding = NamedSharding(mesh, PartitionSpec())
-        # per-solve stats (the bench/metrics surface)
+        # per-solve stats (the metrics surface)
         self.class_count = 0
         self.classes_valid = 0
         self.compression_ratio = 0.0

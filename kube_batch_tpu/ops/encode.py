@@ -44,7 +44,7 @@ actions/xla_allocate).
 
 Dtype: float64 arrays make the XLA path bit-identical to the serial
 float64 Python path (the equivalence property tests run this way on CPU);
-the TPU bench path uses float32, which is exact for milli-CPU-granular
+the TPU path uses float32, which is exact for milli-CPU-granular
 cpu and MiB-granular memory (values stay on a 2^20-multiple grid well
 inside the 24-bit mantissa).
 """

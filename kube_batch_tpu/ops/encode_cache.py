@@ -449,7 +449,8 @@ class TensorArena:
         self.full_uploads = 0
         self.rows_uploaded = 0
         # high-water mark of live device bytes across all slabs+banks,
-        # refreshed by device_view; the bench's HBM column
+        # refreshed by device_view; exported as the arena HBM
+        # watermark gauge
         self.hbm_watermark_bytes = 0
 
     @property

@@ -34,10 +34,11 @@ dims, NOT on the lane dim — probed, see git history):
 
 Equivalence contract: identical op-for-op float32 formulas and identical
 lexicographic tie-breaks as ops/kernels.py, pinned by the pallas ≡ XLA
-property tests (interpret mode on CPU, real kernel on TPU via bench's
-serial-vs-xla bind assertions). The pause/resume protocol for host-only
-(pod-affinity) tasks is identical: the kernel exits with ``paused_at``
-set, the action serial-steps the task and re-enters with patched state.
+property tests (interpret mode on CPU, real kernel on TPU via
+chip_smoke.py's bind parity with the XLA twin and the serial actions).
+The pause/resume protocol for host-only (pod-affinity) tasks is
+identical: the kernel exits with ``paused_at`` set, the action
+serial-steps the task and re-enters with patched state.
 
 Out-of-envelope snapshots (resource rank > 8, > 31 distinct host ports,
 a compat matrix too large for VMEM) fall back to the XLA kernel — never
@@ -1066,7 +1067,7 @@ def block_step_jnp(ivec, fvec, cnode, affw, nalloc, nmax, nihs, nrhs,
                    idle, rel, used, ntasks, nports):
     """jnp twin of the fused block step — identical folded layout and
     float32 formulas, as plain XLA ops. The fast compiled path on
-    non-TPU meshes (virtual-CPU parity tests and bench rows) and the
+    non-TPU meshes (virtual-CPU parity tests) and the
     cross-check oracle the interpret-mode kernel is pinned against."""
     import jax.numpy as jnp
     from jax import lax

@@ -117,8 +117,8 @@ class ShardedSolver:
     winning node (psum-style reduction over the lone sharded axis riding
     ICI); the capacity update touches one node row, which XLA turns into
     a masked local update. See `sharded_solve_allocate` for the one-shot
-    form; bench.py's `xla` twin measures the same program single-chip,
-    which is the per-chip price floor of this path.
+    form; the single-chip `xla` twin runs the same program, which is
+    the per-chip price floor of this path.
     """
 
     def __init__(

@@ -94,7 +94,7 @@ def _default_exchange_batch() -> int:
     Batching only pays when the dispatch it amortizes is overlapped
     work, so K > 1 requires the pipelined-cycles gate (``KBT_PIPELINE``)
     — without it the env knob is inert and the per-iteration exchange
-    runs unchanged. Tests and benches pass ``exchange_batch`` to the
+    runs unchanged. Tests pass ``exchange_batch`` to the
     solver explicitly to exercise the batched program in isolation.
     """
     from kube_batch_tpu import pipeline

@@ -24,7 +24,7 @@ phase with the *next* cycle:
   fraction is ``1 - |[w0,w1] ∩ [d0,d1]| / (d1 - d0)``: 1.0 means the
   dispatch ran entirely under the next cycle's work, 0.0 means the
   fence serialized the cycles after all. Exposed as
-  ``fence.last_overlap_fraction`` for the bench's per-row column;
+  ``fence.last_overlap_fraction`` (tests read it);
   ``pipeline_fence_wait_seconds`` records every wait.
 
 Sessions carry the in-flight work as ``ssn.deferred_dispatch`` (a
@@ -92,8 +92,8 @@ class DispatchFence:
     """Rendezvous between cycle N's deferred dispatch and cycle N+1.
 
     ``arm(future)`` is called by the action after submitting the
-    post-solve phase; ``wait()`` is called at the top of the next cycle
-    (and by the bench harness between repeats). ``wait()`` returning
+    post-solve phase; ``wait()`` is called at the top of the next
+    cycle. ``wait()`` returning
     False means the caller must NOT proceed with a pipelined cycle: the
     dispatch either timed out (still in flight — the future stays armed
     so a later wait can re-join it) or raised (already logged by the
@@ -251,7 +251,7 @@ def submit(cache, fn: Callable[[], None]) -> Future:
 def join_session(ssn, timeout: Optional[float] = None) -> None:
     """Block until ``ssn``'s deferred dispatch (if any) has landed,
     re-raising its exception. close_session calls this before the commit
-    write-back; benches call it before reading binder state. The join
+    write-back; tests call it before reading binder state. The join
     window feeds the measured overlap fraction (the first join after a
     dispatch window records it)."""
     fut = getattr(ssn, "deferred_dispatch", None)

@@ -1469,7 +1469,7 @@ class SchedulerServer:
         # client negotiated): 2 advertises delta watch / txn batches /
         # the binary codec on /backend/v1/version and serves HTTP/1.1
         # keep-alive; 1 pins the pre-v2 surface byte-for-byte (mixed-
-        # version drills and the bench's v1 twin rows pass 1 here).
+        # version drills and the federation smoke's v1 runs pass 1).
         self.wire_protocol = int(wire_protocol)
 
         # Federation mode (--store-backend): this process schedules over

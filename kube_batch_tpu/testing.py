@@ -1,5 +1,6 @@
-"""Builders shared by unit tests, action tests, and the bench harness
-(reference pkg/scheduler/api/test_utils.go and pkg/scheduler/util/test_utils.go).
+"""Builders shared by unit tests, action tests, and the workload
+generators of ``kube_batch_tpu.models`` (reference
+pkg/scheduler/api/test_utils.go and pkg/scheduler/util/test_utils.go).
 """
 
 from __future__ import annotations

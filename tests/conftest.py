@@ -10,7 +10,7 @@ tests):
   IEEE arithmetic; TPU f32 division is approximate and can flip floor/tie
   boundaries against the serial python path;
 - x64: float64 arrays make the XLA path bit-identical to the serial
-  float64 path. The TPU bench path runs float32, which is exact for
+  float64 path. The TPU path runs float32, which is exact for
   milli/MiB-granular quantities (see ops/encode.py).
 """
 

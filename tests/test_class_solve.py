@@ -15,17 +15,15 @@ The invariants pinned here:
 - **degrade, never drop**: the ``solve.class_table`` fault point drops
   the cycle to the uncompressed tier with identical binds and a metered
   degrade;
-- **zero warm recompiles**: 1%-churn sessions (the bench churn row at
-  test scale) run under a CompileSentinel budget of zero;
+- **zero warm recompiles**: 1%-churn sessions run under a
+  CompileSentinel budget of zero;
 - **tooling**: the wide-key native ``class_dedup`` agrees with the
-  np.unique fallback, the class explain path is byte-identical to the
-  per-node one, and ``hack/bench_diff.py`` gates ``compression_ratio``
-  and the parity bit while keeping the solve-cost split informational.
+  np.unique fallback, and the class explain path is byte-identical to
+  the per-node one.
 """
 
 from __future__ import annotations
 
-import importlib.util
 import os
 
 import numpy as np
@@ -36,11 +34,9 @@ import kube_batch_tpu.plugins  # noqa: F401
 from kube_batch_tpu import faults, metrics
 from kube_batch_tpu.conf import parse_scheduler_conf
 from kube_batch_tpu.framework import close_session, open_session
-from kube_batch_tpu.ops import class_solve
 from kube_batch_tpu.ops.class_solve import ENV, _smoke_world, dedup_rows
 from kube_batch_tpu.testing import FakeCache
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # The reference's default conf: drf + proportion fold into the loop
 # state, so the class key carries the fairness planes too.
@@ -430,10 +426,9 @@ def test_streaming_micro_cycle_absorb_patch_class_parity():
 
 
 def test_warm_churn_sessions_zero_recompiles():
-    """The bench churn row at test scale: 1%-class node churn (the
-    resident shape moves with the salt) must re-key classes without
-    moving the power-of-two class bucket — warm sessions compile
-    nothing."""
+    """1%-class node churn (the resident shape moves with the salt)
+    must re-key classes without moving the power-of-two class bucket —
+    warm sessions compile nothing."""
     from kube_batch_tpu.actions.xla_allocate import XlaAllocateAction
     from kube_batch_tpu.analysis.trace.sentinel import CompileSentinel
     from kube_batch_tpu.models import uniform_pool
@@ -450,67 +445,3 @@ def test_warm_churn_sessions_zero_recompiles():
     assert cs.compiles == 0
     assert action.last_solver_tier.startswith("class_")
     assert action.last_class_stats["compression_ratio"] > 10
-
-
-# -- bench_diff: class columns -----------------------------------------------
-
-
-def _bench_diff_mod():
-    spec = importlib.util.spec_from_file_location(
-        "kbt_hack_bench_diff_class", os.path.join(REPO, "hack", "bench_diff.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_diff_compression_ratio_shrink_is_regression():
-    bd = _bench_diff_mod()
-    old = {"uniform_pool_400k_40k_classes": {
-        "p50_s": 10.0, "compression_ratio": 4000.0,
-    }}
-    new = {"uniform_pool_400k_40k_classes": {
-        "p50_s": 10.0, "compression_ratio": 90.0,
-    }}
-    summary = bd.diff_rows(old, new, threshold=0.15)
-    assert summary["ok"] is False
-    assert [f["kind"] for f in summary["findings"]] == ["regression"]
-    assert "compression_ratio" in summary["findings"][0]["msg"]
-    # the reverse direction is an improvement, not a finding
-    summary = bd.diff_rows(new, old, threshold=0.15)
-    assert summary["ok"] is True
-    assert any("compression_ratio" in l for l in summary["improvements"])
-
-
-def test_bench_diff_parity_bit_flip_is_fatal():
-    bd = _bench_diff_mod()
-    old = {"row": {"p50_s": 1.0, "placements_equal_uncompressed": True}}
-    new = {"row": {"p50_s": 0.5, "placements_equal_uncompressed": False}}
-    summary = bd.diff_rows(old, new, threshold=0.15)
-    assert summary["ok"] is False
-    assert [f["kind"] for f in summary["findings"]] == ["parity"]
-
-
-def test_bench_diff_class_split_columns_are_info_only():
-    """The solve-cost split (where the time went) must never flag, and
-    must never mask a real p50 regression either."""
-    bd = _bench_diff_mod()
-    old = {"row": {
-        "p50_s": 10.0, "class_count": 18, "class_group_s": 0.4,
-        "class_kernel_s": 8.0, "class_segments": 196,
-        "class_solve_speedup_vs_uncompressed": 5.4,
-    }}
-    benign = {"row": {
-        "p50_s": 10.1, "class_count": 1400, "class_group_s": 1.4,
-        "class_kernel_s": 9.0, "class_segments": 400,
-        "class_solve_speedup_vs_uncompressed": 2.0,
-    }}
-    summary = bd.diff_rows(old, benign, threshold=0.15)
-    assert summary["ok"] is True and summary["findings"] == []
-    assert any("class_count 18 -> 1400" in l for l in summary["info"])
-
-    regressed = dict(benign["row"], p50_s=20.0)
-    summary = bd.diff_rows(old, {"row": regressed}, threshold=0.15)
-    assert summary["ok"] is False
-    assert [f["kind"] for f in summary["findings"]] == ["regression"]
-    assert "p50_s" in summary["findings"][0]["msg"]

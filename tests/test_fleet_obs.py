@@ -3,7 +3,7 @@ QuantileSketch property checks against pooled-raw ground truth, the
 SLO accountant's LRU cardinality bound, the KBT_FLEET off-switch
 discipline, an in-process scrape->merge aggregator drill, OpenMetrics
 exemplar gating, the KBT-R012 SLO-kind registry analyzer, and the
-bench_diff handling of device-phase telemetry columns.
+measured pipeline overlap and arena HBM accounting.
 
 The heavyweight end-to-end proof (N live loopback shards over the real
 federation wire path) lives in ``python -m kube_batch_tpu.obs.fleet``
@@ -14,7 +14,6 @@ the component contracts that smoke composes.
 from __future__ import annotations
 
 import ast
-import importlib.util
 import json
 import math
 import os
@@ -31,8 +30,6 @@ from kube_batch_tpu.analysis import SourceFile
 from kube_batch_tpu.analysis import registry_consistency
 from kube_batch_tpu.obs import QuantileSketch, SLOAccountant
 from kube_batch_tpu.obs import fleet
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # the bound every sketch consumer (smoke, verify gate, these tests)
 # holds quantiles to: declared alpha plus a 5% margin for the bucket
@@ -493,171 +490,6 @@ def test_live_tree_slo_kind_registry_is_consistent():
     kinds = tuple(obs.SLOAccountant.KINDS)
     assert tuple(metrics._SLO_GAUGES) == kinds
     assert tuple(metrics._FLEET_SLO_GAUGES) == kinds
-
-
-# -- bench_diff: device-phase columns are informational ----------------------
-
-
-def _bench_diff_mod():
-    spec = importlib.util.spec_from_file_location(
-        "kbt_hack_bench_diff", os.path.join(REPO, "hack", "bench_diff.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_diff_never_flags_device_phase_columns():
-    bd = _bench_diff_mod()
-    old = {"sched/2000x300": {
-        "p50_s": 0.100, "solve_device_s": 0.020,
-        "pipeline_overlap_fraction": 0.9,
-        "arena_hbm_watermark_bytes": 1000, "fleet_shards": 2,
-    }}
-    new = {"sched/2000x300": {
-        "p50_s": 0.101, "solve_device_s": 0.080,  # 4x: still only info
-        "pipeline_overlap_fraction": 0.1,
-        "arena_hbm_watermark_bytes": 9000, "fleet_shards": 4,
-    }}
-    summary = bd.diff_rows(old, new, threshold=0.15)
-    assert summary["ok"] is True
-    assert summary["findings"] == []
-    assert len(summary["info"]) == 4
-    assert any("solve_device_s 0.02 -> 0.08" in l for l in summary["info"])
-    assert any("fleet_shards 2 -> 4" in l for l in summary["info"])
-
-
-def test_bench_diff_info_does_not_mask_real_regression():
-    bd = _bench_diff_mod()
-    old = {"row": {"p50_s": 0.100, "solve_device_s": 0.020}}
-    new = {"row": {"p50_s": 0.200, "solve_device_s": 0.021}}
-    summary = bd.diff_rows(old, new, threshold=0.15)
-    assert summary["ok"] is False
-    assert [f["kind"] for f in summary["findings"]] == ["regression"]
-    assert len(summary["info"]) == 1
-
-
-# -- bench_diff: wire-transport columns gate (ISSUE 17) ----------------------
-
-
-def _wire_run(proto, shards, binds_per_s, bytes_per_bind, rtt, batch_mean):
-    return {
-        "protocol": proto, "shards": shards, "binds_per_s": binds_per_s,
-        "wire_bytes_per_bind": bytes_per_bind, "backend_rtt_p50_s": rtt,
-        "txn_batch_mean": batch_mean, "exactly_once": True,
-        "union_parity": True,
-    }
-
-
-def test_bench_diff_catches_v2_throughput_slide_back_to_v1():
-    """The wire ladder's whole point: a v2 cell whose throughput slid
-    back to v1 numbers (and whose byte volume grew back) must be a
-    regression finding in the expanded pseudo-row — flagged, not an
-    [info] line — even though the parent row's p50 is unchanged."""
-    bd = _bench_diff_mod()
-    old = {"federation_scaleout_50k": {
-        "p50_s": 5.0,
-        "wire_runs": [
-            _wire_run(1, 4, 60.0, 15000.0, 0.009, 0.0),
-            _wire_run(2, 4, 85.0, 8600.0, 0.007, 50.0),
-        ],
-    }}
-    # v2 n4 collapses to v1-grade throughput/bytes; coalescing depth
-    # falls to per-gang (txn_batch_mean 50 -> 1); rtt doubles
-    new = {"federation_scaleout_50k": {
-        "p50_s": 5.0,
-        "wire_runs": [
-            _wire_run(1, 4, 60.0, 15000.0, 0.009, 0.0),
-            _wire_run(2, 4, 41.0, 14800.0, 0.014, 1.0),
-        ],
-    }}
-    summary = bd.diff_rows(
-        bd._expand_wire_rows(old), bd._expand_wire_rows(new), threshold=0.15
-    )
-    assert summary["ok"] is False
-    v2_findings = [
-        f for f in summary["findings"]
-        if f["row"] == "federation_scaleout_50k.wire_v2_n4"
-    ]
-    assert {f["kind"] for f in v2_findings} == {"regression"}
-    flagged = " ".join(f["msg"] for f in v2_findings)
-    assert "binds_per_s" in flagged          # higher-is-better shrank
-    assert "wire_bytes_per_bind" in flagged  # lower-is-better grew
-    assert "backend_rtt_p50_s" in flagged
-    assert "txn_batch_mean" in flagged
-    # the untouched v1 twin cell stays quiet
-    assert not any(
-        f["row"] == "federation_scaleout_50k.wire_v1_n4"
-        for f in summary["findings"]
-    )
-
-
-def test_bench_diff_wire_parity_bits_and_improvements():
-    bd = _bench_diff_mod()
-    old = {"fed": {"wire_runs": [_wire_run(2, 8, 50.0, 16000.0, 0.01, 25.0)]}}
-    better = {"fed": {"wire_runs": [_wire_run(2, 8, 70.0, 9000.0, 0.006, 25.0)]}}
-    summary = bd.diff_rows(
-        bd._expand_wire_rows(old), bd._expand_wire_rows(better), threshold=0.15
-    )
-    assert summary["ok"] is True and summary["findings"] == []
-    assert any("binds_per_s" in line for line in summary["improvements"])
-    # a correctness bit going false is a parity finding no number excuses
-    broken = {"fed": {"wire_runs": [
-        dict(_wire_run(2, 8, 70.0, 9000.0, 0.006, 25.0), exactly_once=False)
-    ]}}
-    summary = bd.diff_rows(
-        bd._expand_wire_rows(old), bd._expand_wire_rows(broken), threshold=0.15
-    )
-    assert summary["ok"] is False
-    assert any(
-        f["kind"] == "parity" and "exactly_once" in f["msg"]
-        for f in summary["findings"]
-    )
-
-
-# -- bench_diff: admission-storm columns gate directionally (ISSUE 18) -------
-
-
-def _storm_row(p99, mttr, goodput, shed_low=20, exactly_once=True):
-    return {"admission_storm": {
-        "storm_high_p99_s": p99, "storm_mttr_s": mttr,
-        "storm_goodput_pods_per_s": goodput, "storm_shed_high": 0,
-        "storm_shed_low": shed_low, "exactly_once": exactly_once,
-    }}
-
-
-def test_bench_diff_storm_columns_gate_with_direction():
-    bd = _bench_diff_mod()
-    old = _storm_row(p99=0.9, mttr=1.5, goodput=30.0)
-    # protected-lane tail and MTTR growing, goodput shrinking: three
-    # regressions, each in its own direction
-    worse = _storm_row(p99=1.8, mttr=2.5, goodput=20.0)
-    summary = bd.diff_rows(old, worse, threshold=0.15)
-    assert summary["ok"] is False
-    msgs = [f["msg"] for f in summary["findings"]]
-    assert any("storm_high_p99_s" in m and "lower-is-better" in m for m in msgs)
-    assert any("storm_mttr_s" in m and "lower-is-better" in m for m in msgs)
-    assert any(
-        "storm_goodput_pods_per_s" in m and "higher-is-better" in m
-        for m in msgs
-    )
-    # the same deltas in the healthy direction are improvements
-    summary = bd.diff_rows(worse, old, threshold=0.15)
-    assert summary["ok"] is True and len(summary["improvements"]) == 3
-
-
-def test_bench_diff_storm_shed_counts_are_info_not_findings():
-    bd = _bench_diff_mod()
-    old = _storm_row(p99=0.9, mttr=1.5, goodput=30.0, shed_low=20)
-    new = _storm_row(p99=0.9, mttr=1.5, goodput=30.0, shed_low=80)
-    summary = bd.diff_rows(old, new, threshold=0.15)
-    assert summary["ok"] is True and summary["findings"] == []
-    assert any("storm_shed_low 20 -> 80" in line for line in summary["info"])
-    # but the exactly_once bit flipping is a parity finding, not info
-    broken = _storm_row(p99=0.9, mttr=1.5, goodput=30.0, exactly_once=False)
-    summary = bd.diff_rows(old, broken, threshold=0.15)
-    assert summary["ok"] is False
-    assert [f["kind"] for f in summary["findings"]] == ["parity"]
 
 
 # -- measured pipeline overlap -----------------------------------------------

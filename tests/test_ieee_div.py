@@ -2,7 +2,7 @@
 
 The serial oracle divides with CPython's IEEE-754 semantics; some XLA
 backends lower division to a reciprocal-multiply that lands 1+ ulp off
-(measured on the TPU build this repo benches on), which flipped
+(measured on the TPU build this repo runs on), which flipped
 proportion share ties and least-requested floor boundaries in the
 device kernels (ops/kernels.py ieee_div docstring). These tests pin
 the fix: kernel division must reproduce numpy's quotient bit-for-bit
@@ -61,7 +61,7 @@ def test_f64_division_bit_exact_on_cpu_backend():
 def test_f32_division_bit_exact_on_tpu_backend():
     """The backend the drift was measured on. Under the suite's conftest
     (platform pinned to cpu) the TPU may be uninitializable — skip then;
-    the bench/driver path still exercises it for real."""
+    chip_smoke.py still exercises it for real."""
     try:
         tpu = jax.devices("tpu")[0]
     except Exception:  # noqa: BLE001 -- platform pinned or absent

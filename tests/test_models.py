@@ -1,5 +1,5 @@
-"""Sanity checks on the synthetic workload generators (the bench configs
-of BASELINE.md) — counts, queues, scalar resources, determinism."""
+"""Sanity checks on the synthetic workload generators (the config
+shapes of BASELINE.md) — counts, queues, scalar resources, determinism."""
 
 from kube_batch_tpu.api.types import TaskStatus
 from kube_batch_tpu.models import (

@@ -384,11 +384,11 @@ class TestActionUsesNative:
         from kube_batch_tpu.framework import close_session, get_action, open_session
         from kube_batch_tpu.models import synthetic
         from kube_batch_tpu.testing import FakeCache
-        from bench import TIERS_YAML
+        from test_xla_allocate import DEFAULT_TIERS_YAML
 
         def run():
             cache = FakeCache(synthetic(120, 16))
-            ssn = open_session(cache, parse_scheduler_conf(TIERS_YAML).tiers)
+            ssn = open_session(cache, parse_scheduler_conf(DEFAULT_TIERS_YAML).tiers)
             get_action("xla_allocate").execute(ssn)
             binds = dict(cache.binder.binds)
             close_session(ssn)

@@ -5,8 +5,8 @@ oracle (tests/test_xla_allocate.py); these tests pin the fused Pallas
 kernel (ops/pallas_solve.py) against the XLA kernel, decision for
 decision, on the same float32 snapshots. On CPU the Pallas kernel runs
 in interpreter mode; on the real chip the compiled kernel is covered by
-bench.py's serial-vs-xla bind assertions (the action auto-selects the
-Pallas path on TPU).
+chip_smoke.py's bind parity with the XLA twin and the serial actions
+(the action auto-selects the Pallas path on TPU).
 """
 
 import numpy as np

@@ -360,7 +360,7 @@ def test_batched_exchange_pause_resume_through_action():
     """KBT_PIPELINE + KBT_EXCHANGE_BATCH through the real action routing,
     including the segmented pod-affinity pause/resume hybrid: binds must
     match the serial path, and the action must account the amortized
-    iterations (the bench rows read the same counter)."""
+    iterations."""
     from kube_batch_tpu import pipeline
     from kube_batch_tpu.actions.xla_allocate import XlaAllocateAction
 
