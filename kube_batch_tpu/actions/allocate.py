@@ -95,6 +95,7 @@ class AllocateAction(Action):
                 # (allocate.go:139-145).
                 if job.nodes_fit_delta:
                     job.nodes_fit_delta = {}
+                    job.rev += 1
 
                 candidates = predicate_nodes(task, all_nodes, predicate_fn)
                 if not candidates:
@@ -129,6 +130,7 @@ class AllocateAction(Action):
                     delta = node.idle.clone()
                     delta.fit_delta(task.init_resreq)
                     job.nodes_fit_delta[node.name] = delta
+                    job.rev += 1
                     if task.init_resreq.less_equal(node.releasing):
                         log.V(3).infof(
                             "pipelining task <%s/%s> onto releasing node <%s>",

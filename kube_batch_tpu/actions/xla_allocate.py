@@ -851,6 +851,7 @@ class XlaAllocateAction(Action):
         row = int(s.paused_at)
         task = enc.tasks[row]
         job = ssn.jobs[task.job]
+        job.rev += 1  # its nodes_fit_delta is rewritten below
         jrow = int(s.cur)
         all_nodes = get_node_list(ssn.nodes)
 
@@ -1011,7 +1012,11 @@ class _Replayer:
         task = self.enc.tasks[row]
         job = ssn.jobs[task.job]
         hostname = self.enc.node_names[nrow]
+        node = ssn.nodes[hostname]
         status = TaskStatus.ALLOCATED if kind == KIND_ALLOCATED else TaskStatus.PIPELINED
+        # both change below, past their mutator methods
+        job.rev += 1
+        node.rev += 1
 
         if kind == KIND_ALLOCATED:
             ssn.cache.allocate_volumes(task, hostname)
@@ -1032,7 +1037,6 @@ class _Replayer:
             job.allocated.add(task.resreq)
 
         # node: task map entry (a clone, node_info.go:117) + deferred sums
-        node = ssn.nodes[hostname]
         node.tasks[self.task_keys[row]] = task.clone_for_residency()
         buf = self._node_buf.get(nrow)
         if buf is None:
@@ -1148,6 +1152,11 @@ class _Replayer:
 
         touched_n_l = touched_n.tolist()
         nodes_t = [self.node_by_row[nrow] for nrow in touched_n_l]
+        # every node and job this segment touches changes past its
+        # mutator methods: the sums here, the task maps and status
+        # indexes below
+        for node in nodes_t:
+            node.rev += 1
         axpy([n.idle for n in nodes_t], n_alloc_vec, -1)
         axpy([n.releasing for n in nodes_t], n_pipe_vec, -1)
         axpy([n.used for n in nodes_t], n_alloc_vec + n_pipe_vec, 1)
@@ -1172,6 +1181,8 @@ class _Replayer:
         drf = self.drf
         touched_j_l = touched_j.tolist()
         jobs_t = [self.enc.jobs[jrow] for jrow in touched_j_l]
+        for job in jobs_t:
+            job.rev += 1
         wa_pos = np.searchsorted(touched_j, wa)
         jobs_wa = [jobs_t[p] for p in wa_pos.tolist()]
         axpy([j.allocated for j in jobs_wa], j_alloc[wa_pos], 1)
@@ -1412,6 +1423,7 @@ class _Replayer:
             allocated = job.task_status_index.get(TaskStatus.ALLOCATED)
             if not allocated:
                 continue
+            job.rev += 1
             if job.uid not in self.stepped_jobs:
                 # Pure-bulk gang: every task came through bulk_assign, so
                 # it is volume-less with volume_ready=True — no per-task
@@ -1532,6 +1544,8 @@ class _Replayer:
                 np.asarray(ready_cnt)[:jn] >= np.asarray(job_min)[:jn]
             )
             mask = mask_arr.astype(np.uint8).tobytes()
+            # every job in the mask took an Allocated event in this
+            # replay, whose apply_one/apply_upto bumped its rev
             try:
                 if faults.should_fire("native.dispatch"):
                     raise TypeError("fault injected: native.dispatch")

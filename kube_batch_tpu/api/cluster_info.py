@@ -9,7 +9,10 @@ from kube_batch_tpu.api.queue_info import QueueInfo
 
 
 class ClusterInfo:
-    __slots__ = ("jobs", "nodes", "queues")
+    """``serial`` numbers the cache's snapshots (0: not from one); a
+    session hands it back at close to settle the snapshot's clones."""
+
+    __slots__ = ("jobs", "nodes", "queues", "serial")
 
     def __init__(
         self,
@@ -20,6 +23,7 @@ class ClusterInfo:
         self.jobs: dict[str, JobInfo] = jobs or {}
         self.nodes: dict[str, NodeInfo] = nodes or {}
         self.queues: dict[str, QueueInfo] = queues or {}
+        self.serial = 0
 
     def __repr__(self) -> str:
         return (

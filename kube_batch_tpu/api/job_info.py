@@ -151,7 +151,12 @@ class FitError:
 class JobInfo:
     """The gang unit — one PodGroup (or legacy PDB) worth of tasks
     (reference job_info.go:127-426). Maintains the TaskStatusIndex and the
-    Allocated/TotalRequest aggregates through every mutation."""
+    Allocated/TotalRequest aggregates through every mutation.
+
+    ``rev`` counts the changes to what a clone copies, as on
+    :class:`NodeInfo`: the mutator methods bump it, and so must any code
+    that rewrites the status index, the aggregates or ``nodes_fit_delta``
+    directly (the device path's bulk replay)."""
 
     def __init__(self, uid: str, *tasks: TaskInfo) -> None:
         self.uid = uid
@@ -169,25 +174,36 @@ class JobInfo:
         self.creation_timestamp = 0.0
         self.pod_group: Optional[PodGroup] = None
         self.pdb: Optional[PodDisruptionBudget] = None
+        self.rev = 0
         for t in tasks:
             self.add_task_info(t)
 
     # -- pod group / pdb binding -------------------------------------------
 
-    def set_pod_group(self, pg: PodGroup) -> None:
-        """reference job_info.go:183-192."""
-        self.name = pg.name
-        self.namespace = pg.metadata.namespace
-        self.min_available = pg.spec.min_member
-        self.queue = pg.spec.queue
-        self.creation_timestamp = pg.metadata.creation_timestamp
+    def set_pod_group(self, pg: PodGroup, default_queue: str = "") -> None:
+        """reference job_info.go:183-192; a PodGroup that names no queue
+        lands in ``default_queue``.
+
+        Bumps ``rev`` only when it changes what a clone copies: the
+        session's status write-back re-delivers the very PodGroup the job
+        holds after every cycle, which leaves the job as it was."""
+        fields = (pg.name, pg.metadata.namespace, pg.spec.min_member,
+                  pg.spec.queue or default_queue, pg.metadata.creation_timestamp)
+        held = (self.name, self.namespace, self.min_available, self.queue,
+                self.creation_timestamp)
+        if pg is not self.pod_group or fields != held:
+            self.rev += 1
+        (self.name, self.namespace, self.min_available, self.queue,
+         self.creation_timestamp) = fields
         self.pod_group = pg
 
     def unset_pod_group(self) -> None:
+        self.rev += 1
         self.pod_group = None
 
     def set_pdb(self, pdb: PodDisruptionBudget) -> None:
         """Legacy gang source (reference job_info.go:195-203)."""
+        self.rev += 1
         self.name = pdb.name
         self.namespace = pdb.metadata.namespace
         self.min_available = pdb.min_available
@@ -195,6 +211,7 @@ class JobInfo:
         self.pdb = pdb
 
     def unset_pdb(self) -> None:
+        self.rev += 1
         self.pdb = None
 
     # -- task bookkeeping ---------------------------------------------------
@@ -219,6 +236,7 @@ class JobInfo:
 
     def add_task_info(self, ti: TaskInfo) -> None:
         """reference job_info.go:233-242."""
+        self.rev += 1
         self.tasks[ti.uid] = ti
         self._add_task_index(ti)
         self.total_request.add(ti.resreq)
@@ -241,6 +259,7 @@ class JobInfo:
                 f"failed to find task <{ti.namespace}/{ti.name}> "
                 f"in job <{self.namespace}/{self.name}>"
             )
+        self.rev += 1
         self.total_request.sub(task.resreq)
         if allocated_status(task.status):
             self.allocated.sub(task.resreq)

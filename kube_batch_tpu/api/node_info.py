@@ -14,7 +14,13 @@ from kube_batch_tpu.api.types import TaskStatus
 class NodeInfo:
     """Idle/Used/Releasing/Allocatable/Capability accounting plus the task
     map. Tasks are stored as clones so later status changes on the caller's
-    TaskInfo cannot corrupt node accounting (reference node_info.go:117)."""
+    TaskInfo cannot corrupt node accounting (reference node_info.go:117).
+
+    ``rev`` counts the changes to what a clone copies: every mutator
+    method bumps it, and so must any code that writes the task map or the
+    accounting directly (the device path's bulk replay). The cache's
+    snapshot hands an unchanged clone to the next session only while its
+    own and its source's ``rev`` still read what it recorded."""
 
     def __init__(self, node: Optional[Node] = None) -> None:
         self.name = ""
@@ -26,6 +32,7 @@ class NodeInfo:
         self.capability = Resource.empty()
         self.tasks: dict[str, TaskInfo] = {}
         self.other = None
+        self.rev = 0
         if node is not None:
             self.name = node.name
             self.node = node
@@ -78,6 +85,7 @@ class NodeInfo:
         """Reset accounting from a fresh node object, replaying resident
         tasks (reference node_info.go:89-105). Overcommit-tolerant for
         the same reason as clone(): the replay records facts."""
+        self.rev += 1
         self.name = node.name
         self.node = node
         self.allocatable = Resource.from_resource_list(node.allocatable)
@@ -109,6 +117,7 @@ class NodeInfo:
                 f"task <{task.namespace}/{task.name}> already on node <{self.name}>"
             )
         ti = task.clone()
+        self.rev += 1
         if self.node is not None:
             sub = Resource.sub_overcommit if overcommit else Resource.sub
             if ti.status == TaskStatus.RELEASING:
@@ -129,6 +138,7 @@ class NodeInfo:
             raise KeyError(
                 f"failed to find task <{ti.namespace}/{ti.name}> on host <{self.name}>"
             )
+        self.rev += 1
         if self.node is not None:
             if task.status == TaskStatus.RELEASING:
                 self.releasing.sub(task.resreq)

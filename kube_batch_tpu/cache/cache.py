@@ -17,7 +17,8 @@ add/update/delete callbacks. Everything downstream is kept:
 - terminated jobs are garbage-collected through the ``deletedJobs``
   queue (cache.go:480-510);
 - Snapshot() deep-clones jobs/nodes/queues for the session
-  (cache.go:535-585).
+  (cache.go:535-585), handing out again the previous snapshot's node
+  and job clones that neither the cache nor that session changed.
 
 The default write side is the store itself (the in-process stand-in for
 the API server): Bind writes ``pod.node_name`` back through
@@ -124,6 +125,17 @@ def create_shadow_pod_group(pod: Pod) -> PodGroup:
     )
     pg.status.phase = PodGroupPhase.INQUEUE
     return pg
+
+
+# Task statuses a job clone may hold and still be handed to the next
+# session (SchedulerCache.settle_snapshot): no action works on such a job.
+_RESIDENT = frozenset((TaskStatus.RUNNING, TaskStatus.BOUND))
+
+
+def _unchanged(rec: tuple) -> bool:
+    """A snapshot record ``(source, source rev, clone, clone rev)`` whose
+    source and clone both still read the revisions recorded."""
+    return rec[0].rev == rec[1] and rec[2].rev == rec[3]
 
 
 def _is_terminated(status: TaskStatus) -> bool:
@@ -553,6 +565,14 @@ class SchedulerCache:
         # version every conditional dispatch carries (#: guarded_by _mutex
         # for writes; dispatch reads the int atomically).
         self._snapshot_version = 0
+        # Clone reuse across snapshots (see snapshot()): the serial of the
+        # last snapshot handed out with its records, name/uid ->
+        # (source, source rev, clone, clone rev), until its session
+        # settles them; then the settled records the next snapshot may
+        # reuse from.
+        self._snapshot_serial = 0  #: guarded_by _mutex
+        self._handed: Optional[tuple[int, dict, dict]] = None  #: guarded_by _mutex
+        self._settled: Optional[tuple[dict, dict]] = None  #: guarded_by _mutex
         self._writer: Optional[ThreadPoolExecutor] = None
         self._workers: list[threading.Thread] = []
         self._stop = threading.Event()
@@ -696,8 +716,7 @@ class SchedulerCache:
             ti.job = job_key(pg.metadata.namespace, pg.name)
             if ti.job not in self.jobs:
                 job = JobInfo(ti.job)
-                job.set_pod_group(pg)
-                job.queue = self.default_queue
+                job.set_pod_group(pg, default_queue=self.default_queue)
                 self.jobs[ti.job] = job
         elif ti.job not in self.jobs:
             self.jobs[ti.job] = JobInfo(ti.job)
@@ -872,9 +891,7 @@ class SchedulerCache:
         jid = job_key(pg.metadata.namespace, pg.name)
         if jid not in self.jobs:
             self.jobs[jid] = JobInfo(jid)
-        self.jobs[jid].set_pod_group(pg)
-        if not pg.spec.queue:
-            self.jobs[jid].queue = self.default_queue
+        self.jobs[jid].set_pod_group(pg, default_queue=self.default_queue)
 
     def add_pod_group(self, pg: PodGroup) -> None:
         with self._mutex:
@@ -930,8 +947,10 @@ class SchedulerCache:
         self.jobs[jid].set_pdb(pdb)
         # PDBs predate queues; they land in the default queue — unless a
         # PodGroup already assigned one (don't stomp it).
-        if not self.jobs[jid].queue:
-            self.jobs[jid].queue = self.default_queue
+        job = self.jobs[jid]
+        if not job.queue:
+            job.queue = self.default_queue
+            job.rev += 1
 
     # -- queue handlers (reference event_handlers.go:607-699) --------------
 
@@ -1502,6 +1521,14 @@ class SchedulerCache:
     # -- snapshot (reference cache.go:535-585) -----------------------------
 
     def snapshot(self) -> ClusterInfo:
+        """Clone the mirror for one session (reference cache.go:535-585).
+
+        A clone the previous snapshot handed out is handed out again when
+        that snapshot's session settled it (:meth:`settle_snapshot`), the
+        clone's source is still the object in the mirror, and neither has
+        changed since: both ``rev``s read what was recorded. A job clone
+        must also carry the priority resolved now. The loops keep the
+        mirror's order."""
         self.publish_ingest()
         reset = getattr(self.volume_binder, "reset", None)
         if reset is not None:
@@ -1511,15 +1538,34 @@ class SchedulerCache:
             # Stamp the store version this snapshot solves over — every
             # conditional dispatch until the next snapshot carries it.
             self._snapshot_version = getattr(self.store, "version", 0)
+            kept_nodes, kept_jobs = self._settled or ({}, {})
+            self._settled = None
+            self._snapshot_serial += 1
+            snapshot.serial = self._snapshot_serial
+            nodes_rec: dict[str, tuple] = {}
+            jobs_rec: dict[str, tuple] = {}
             with obs.span("snapshot.nodes") as sp:
+                reused = 0
                 for name, node in self.nodes.items():
-                    snapshot.nodes[name] = node.clone()
+                    rec = kept_nodes.get(name)
+                    if rec is not None and rec[0] is node and _unchanged(rec):
+                        reused += 1
+                    else:
+                        clone = node.clone()
+                        rec = (node, node.rev, clone, clone.rev)
+                    snapshot.nodes[name] = rec[2]
+                    nodes_rec[name] = rec
+                cloned = len(nodes_rec) - reused
+                metrics.register_snapshot_objects("node", reused, cloned)
                 if sp is not obs.NOOP_SPAN:
                     sp.set_attr("objects", len(snapshot.nodes))
                     sp.set_attr("tasks", sum(len(n.tasks) for n in snapshot.nodes.values()))
+                    sp.set_attr("reused", reused)
+                    sp.set_attr("cloned", cloned)
             for name, q in self.queues.items():
                 snapshot.queues[name] = q.clone()
             with obs.span("snapshot.jobs") as sp:
+                reused = 0
                 for uid, job in self.jobs.items():
                     if job.pod_group is None and job.pdb is None:
                         log.V(4).infof("Job <%s> has no scheduling spec, ignored", uid)
@@ -1535,15 +1581,56 @@ class SchedulerCache:
                         pc = self.priority_classes.get(job.pod_group.spec.priority_class_name)
                         if pc is not None:
                             job.priority = pc.value
-                    snapshot.jobs[uid] = job.clone()
+                    rec = kept_jobs.get(uid)
+                    if (
+                        rec is not None
+                        and rec[0] is job
+                        and _unchanged(rec)
+                        and rec[2].priority == job.priority
+                    ):
+                        reused += 1
+                    else:
+                        clone = job.clone()
+                        rec = (job, job.rev, clone, clone.rev)
+                    snapshot.jobs[uid] = rec[2]
+                    jobs_rec[uid] = rec
+                cloned = len(jobs_rec) - reused
+                metrics.register_snapshot_objects("job", reused, cloned)
                 if sp is not obs.NOOP_SPAN:
                     sp.set_attr("objects", len(snapshot.jobs))
                     sp.set_attr("tasks", sum(len(j.tasks) for j in snapshot.jobs.values()))
+                    sp.set_attr("reused", reused)
+                    sp.set_attr("cloned", cloned)
+            self._handed = (snapshot.serial, nodes_rec, jobs_rec)
             log.V(3).infof(
                 "Snapshot: %d jobs, %d queues, %d nodes",
                 len(snapshot.jobs), len(snapshot.queues), len(snapshot.nodes),
             )
             return snapshot
+
+    def settle_snapshot(self, serial: int) -> None:
+        """The session over snapshot ``serial`` closed without discard:
+        its clones changed only through the revision-counting mutators,
+        so the next snapshot may hand the unchanged ones out again. A
+        later snapshot, or none, settles nothing. Dropped here, so that
+        they die with the session: the records of clones already
+        changed, and of jobs with a task that is not Running or Bound
+        (the jobs an action works on carry per-session state, such as
+        ``nodes_fit_delta``, and are always cloned afresh)."""
+        with self._mutex:
+            handed = self._handed
+            if handed is None or handed[0] != serial:
+                return
+            self._handed = None
+            _, nodes_rec, jobs_rec = handed
+            self._settled = (
+                {k: r for k, r in nodes_rec.items() if _unchanged(r)},
+                {
+                    k: r
+                    for k, r in jobs_rec.items()
+                    if _unchanged(r) and r[2].task_status_index.keys() <= _RESIDENT
+                },
+            )
 
     def clone_jobs_for_stream(
         self, job_keys

@@ -87,6 +87,11 @@ class Session:
         # joins it before running a later action over the same session.
         self.deferred_dispatch = None
 
+        # Serial of the cache snapshot this session opened over (0: an
+        # explicit world, or a cache that numbers none); close_session
+        # settles it unless the session is discarded.
+        self.snapshot_serial: int = 0
+
         self.plugins: dict[str, Plugin] = {}
         self.event_handlers: list[EventHandler] = []
         self.job_order_fns: dict[str, Callable] = {}
@@ -522,6 +527,7 @@ def _open_session(cache, tiers, action_arguments, world) -> Session:
             ssn.jobs = snapshot.jobs
             ssn.nodes = snapshot.nodes
             ssn.queues = snapshot.queues
+            ssn.snapshot_serial = getattr(snapshot, "serial", 0)
             sspan.set_attr("jobs", len(ssn.jobs))
             sspan.set_attr("nodes", len(ssn.nodes))
     else:
@@ -574,7 +580,8 @@ def close_session(ssn: Session, discard: bool = False) -> None:
     hard-deadline cycle abort, recovery/budget.py) the write-back is
     skipped: the aborted cycle's session state is rolled back wholesale
     — Statement.discard at cycle granularity — leaving the cache/store
-    byte-identical to the cycle's start."""
+    byte-identical to the cycle's start — and the snapshot is not
+    settled, so the next one clones everything afresh."""
     with obs.span("session.close"):
         _close_session(ssn, discard)
 
@@ -613,6 +620,11 @@ def _close_session(ssn: Session, discard: bool) -> None:
                     continue
                 job.pod_group.status = _job_status(ssn, job)
                 ssn.cache.update_job_status(job)
+        # The session's clones are done with: the cache may hand the
+        # unchanged ones to the next session (SchedulerCache.snapshot).
+        settle = getattr(ssn.cache, "settle_snapshot", None)
+        if settle is not None and ssn.snapshot_serial:
+            settle(ssn.snapshot_serial)
 
     ssn.jobs = {}
     ssn.nodes = {}

@@ -335,6 +335,12 @@ cache_event_seconds = Counter(
     "Seconds the scheduler cache's event handlers took, by kind and verb "
     "(timed only while tracing is on; folded in once per snapshot)",
 )
+snapshot_objects = Counter(
+    f"{_SUBSYSTEM}_snapshot_objects_total",
+    "Nodes and jobs the scheduler cache's snapshots handed out, by kind "
+    "(node, job) and how: reused (the previous snapshot's clone, unchanged) "
+    "or cloned",
+)
 
 # -- cyclic garbage collector (kube_batch_tpu.utils.collector) --------------
 gc_collections = Counter(
@@ -737,6 +743,11 @@ def register_encode_cache_invalidation(reason: str, n: int = 1) -> None:
     encode_cache_invalidations.inc({"reason": reason}, by=n)
 
 
+def register_snapshot_objects(kind: str, reused: int, cloned: int) -> None:
+    snapshot_objects.inc({"kind": kind, "how": "reused"}, by=reused)
+    snapshot_objects.inc({"kind": kind, "how": "cloned"}, by=cloned)
+
+
 def set_encode_warm_fraction(fraction: float) -> None:
     encode_warm_fraction.set(fraction)
 
@@ -1023,6 +1034,7 @@ def render_prometheus_text() -> str:
         watch_relists,
         cache_events,
         cache_event_seconds,
+        snapshot_objects,
         gc_collections,
         gc_pause_seconds,
         encode_cache_hits,

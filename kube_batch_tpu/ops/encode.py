@@ -95,6 +95,15 @@ def _bucket(n: int, minimum: int = 8) -> int:
     return 1 << (size - 1).bit_length()
 
 
+def _job_bucket(n: int, tasks: int) -> int:
+    """Job-axis bucket: a power of two, and at least an eighth of the
+    task bucket ``tasks`` (up to 256). Gangs carry several tasks each, so
+    the two axes move together; a cycle whose gang count dips just under
+    a power of two while its task count stays in its bucket keeps the
+    kernel shape it already compiled instead of compiling a new pair."""
+    return _bucket(n, max(4, min(tasks // 8, 256)))
+
+
 def _node_bucket(n: int) -> int:
     """Node-axis bucket: next multiple of 128 (one TPU lane row).
 
@@ -558,7 +567,7 @@ def encode_session(
     t_n, n_n, j_n, q_n = len(task_list), len(node_list), len(job_list), len(queue_list)
     T = _bucket(t_n) if pad else max(t_n, 1)
     N = _node_bucket(n_n) if pad else max(n_n, 1)
-    J = _bucket(j_n, 4) if pad else max(j_n, 1)
+    J = _job_bucket(j_n, T) if pad else max(j_n, 1)
     Q = _bucket(q_n, 2) if pad else max(q_n, 1)
 
     # -- ports ---------------------------------------------------------------

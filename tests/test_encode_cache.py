@@ -22,7 +22,7 @@ from kube_batch_tpu.conf import parse_scheduler_conf
 from kube_batch_tpu.framework import close_session, get_action, open_session
 from kube_batch_tpu.models import multi_queue, synthetic
 from kube_batch_tpu.ops import encode_cache
-from kube_batch_tpu.ops.encode import encode_session
+from kube_batch_tpu.ops.encode import _bucket, _job_bucket, encode_session
 from kube_batch_tpu.testing import (
     FakeCache,
     build_node,
@@ -385,3 +385,20 @@ def test_arena_device_view_passthrough():
     assert view["node_gid"] is arrays["node_gid"]
     np.testing.assert_array_equal(np.asarray(view["node_idle"]), arrays["node_idle"])
     np.testing.assert_array_equal(np.asarray(view["compat"]), arrays["compat"])
+
+
+@pytest.mark.parametrize("gangs", [117, 127, 128, 136, 204])
+def test_job_bucket_follows_the_task_bucket(gangs):
+    """Gangs of ten whose count dips under a power of two keep the kernel
+    shape of their task bucket: (2048, 256) for 1,025-2,048 tasks, the
+    pair a cycle of 136 gangs compiles."""
+    T = _bucket(gangs * 10)
+    assert (T, _job_bucket(gangs, T)) == (2048, 256)
+
+
+@pytest.mark.parametrize("tasks,jobs,want", [
+    (5, 5, 8), (30, 3, 4), (64, 2, 8), (4096, 231, 256), (4096, 299, 512),
+    (16384, 898, 1024), (16384, 1197, 2048), (65536, 10, 256), (300, 300, 512),
+])
+def test_job_bucket_is_a_power_of_two_over_a_floor(tasks, jobs, want):
+    assert _job_bucket(jobs, _bucket(tasks)) == want

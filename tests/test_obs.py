@@ -260,8 +260,11 @@ def test_full_cycle_span_tree(tmp_path, tracing):
     for name in ("encode", "solve", "replay", "dispatch"):
         assert parent[name] == "action.xla_allocate", name
     assert "replay" not in _ancestors(one["dispatch"], by_id)
-    assert one["snapshot.nodes"]["attrs"] == {"objects": 4, "tasks": 0}
-    assert one["snapshot.jobs"]["attrs"] == {"objects": 2, "tasks": 8}
+    # the cache's first snapshot: nothing to reuse yet
+    assert one["snapshot.nodes"]["attrs"] == {"objects": 4, "tasks": 0,
+                                              "reused": 0, "cloned": 4}
+    assert one["snapshot.jobs"]["attrs"] == {"objects": 2, "tasks": 8,
+                                             "reused": 0, "cloned": 2}
     assert one["replay"]["attrs"] == {"gangs": 2, "tasks": 8}
     covered = sum(s["dur_us"] for s in cycle_spans
                   if s["parent_id"] == root["span_id"])
